@@ -19,8 +19,12 @@ import sys
 
 
 def main(quick: bool = False) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import bench_lm, bench_paper_mode, bench_serve, bench_snp, \
         bench_tree
+
+    enable_compile_cache()
 
     sweeps = [
         lambda: bench_snp.rows(quick),

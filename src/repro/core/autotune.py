@@ -22,6 +22,8 @@ module makes the choice automatic.  Decision flow (DESIGN.md §3
         │                        bench baseline (interpret-mode kernels
         │                        are never extrapolated far past their
         │                        measured support)
+        │   (every stage skips a backend whose kernel ``compile_refusal``
+        │    names this shape on this platform)
         └─ 3. degree heuristic — ``SystemPlan.for_system(mode="static")``
                                  (the caller falls through when this
                                  module returns ``None``)
@@ -357,7 +359,8 @@ def lookup(sig: WorkloadSignature, *,
             if key in table:
                 choice = _entry_to_choice(table[key], source=source)
                 if choice is not None and _usable(
-                        choice, sharded=sharded, semantics=sig.semantics):
+                        choice, sharded=sharded, semantics=sig.semantics) \
+                        and _compilable(choice, sig):
                     return choice
     return None
 
@@ -372,6 +375,20 @@ def _usable(choice: TunedChoice, *, sharded: bool,
     if not sup:
         return False
     return choice.encoding == "auto" or choice.encoding in sup
+
+
+def _compilable(choice: TunedChoice, sig: WorkloadSignature) -> bool:
+    """Whether ``choice``'s kernel, at its block shape, compiles for
+    ``sig``'s system on this platform — the backend's own
+    ``compile_refusal``, so the planner never picks what ``lower`` would
+    refuse (backends without the hook always compile)."""
+    from .backend import get_backend, resolve_kernel
+    be = get_backend(choice.backend)
+    if getattr(be, "compile_refusal", None) is None:
+        return True
+    if choice.kernel() is not None:
+        be = resolve_kernel(be, SystemPlan(kernel=choice.kernel()))
+    return be.compile_refusal(sig.m, sig.n, semantics=sig.semantics) is None
 
 
 # ---------------------------------------------------------------------------
@@ -434,12 +451,14 @@ def model_choice(sig: WorkloadSignature, *,
         us = math.exp(logA + p * math.log(max(sig.work, 1.0)))
         if best is None or us < best.us_per_step:
             cfg = _BENCH_KERNELS.get(backend)
-            best = TunedChoice(
+            choice = TunedChoice(
                 backend=backend,
                 block_b=cfg.block_b if cfg else None,
                 block_t=cfg.block_t if cfg else None,
                 block_n=cfg.block_n if cfg else None,
                 us_per_step=us, source="model")
+            if _compilable(choice, sig):
+                best = choice
     return best
 
 
@@ -476,7 +495,7 @@ def default_candidates(sig: WorkloadSignature, *,
                        for bb, bt, bn in blocks)
         else:
             out.append(TunedChoice(backend=name))
-    return out
+    return [c for c in out if _compilable(c, sig)]
 
 
 def _time_step(be, comp, configs, T: int, *, reps: int) -> float:

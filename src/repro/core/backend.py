@@ -17,9 +17,11 @@ workload.  This module is that seam:
 * :class:`RefBackend` (``"ref"``) — the pure-jnp oracle
   (:func:`~repro.core.semantics.next_configs`).
 * :class:`PallasBackend` (``"pallas"``) — the fused TPU kernel
-  (:func:`repro.kernels.snp_step.ops.snp_step`); interpret mode on CPU,
-  ``interpret=False`` on real TPUs.  Does not materialize the spiking
-  vectors, so ``StepOut.spiking`` is ``None``.
+  (:func:`repro.kernels.snp_step.ops.snp_step`); compiled on a TPU and
+  emulated (Pallas interpret mode) anywhere else — ``interpret=None``
+  resolves from the platform at trace time
+  (:func:`repro.core.plan.resolve_interpret`).  Does not materialize the
+  spiking vectors, so ``StepOut.spiking`` is ``None``.
 * :class:`SparseBackend` (``"sparse"``) — gather/segment-sum over the
   ELL/segment encoding (:class:`~repro.core.matrix.CompiledSparseSNP`);
   ``O(B·T·m·degree)`` work and memory, the scaling path for large systems
@@ -45,7 +47,10 @@ hybrid :class:`~repro.core.matrix.CompiledSparseSNP`, neuron-axis
 :class:`~repro.core.plan.ShardedCompiled`), and hand it to ``lower``.
 The **default plan is bit-identical** to each backend's historical
 encoding, and a plan a backend cannot honor is a ``ValueError``, never a
-silent reinterpretation or downgrade.  Consumers resolve backends by name
+silent reinterpretation or downgrade.  The same holds for a shape a
+compiled kernel cannot run: the Pallas backends' ``compile_refusal``
+names it, ``compile``/``lower`` raise it, and the planner never picks a
+refused backend for that shape.  Consumers resolve backends by name
 and call ``compile`` once, so a new encoding lights up every workload
 with no consumer changes — and plan choice is orthogonal to backend
 choice across the whole matrix.
@@ -65,7 +70,8 @@ import jax.numpy as jnp
 from .matrix import (CompiledAny, CompiledSNP, CompiledSparseSNP,
                      compile_system, compile_system_sparse, is_delayed)
 from .plan import (KernelConfig, ShardedCompiled, SystemPlan,
-                   compile_sharded, is_sharded, lower_shard_dense)
+                   compile_sharded, is_sharded, lower_shard_dense,
+                   resolve_interpret)
 from .semantics import (StepOut, delayed_next_configs, next_configs,
                         sparse_delayed_next_configs, sparse_next_configs)
 from .system import SNPSystem
@@ -196,6 +202,29 @@ def _plan_or_default(plan: Optional[SystemPlan]) -> SystemPlan:
     return SystemPlan() if plan is None else plan
 
 
+def _kernel_shape(compiled: "CompiledLike") -> Tuple[int, int, str, int]:
+    """``(m, n, semantics, halo width)`` a fused kernel sees for an
+    encoding: one shard's slice and halo under a neuron-axis partition."""
+    if is_sharded(compiled):
+        return (compiled.shard_size, compiled.arrays.rule_neuron.shape[1],
+                "no_delays", compiled.num_shards * compiled.halo_width)
+    return (compiled.num_neurons, compiled.num_rules,
+            "delays" if is_delayed(compiled) else "no_delays", 0)
+
+
+def _refuse_uncompilable(backend: "StepBackend", m: int, n: int,
+                         semantics: str = "no_delays", halo: int = 0) -> None:
+    """Raise the backend's ``compile_refusal`` for this shape, if any, as
+    a ``ValueError`` — at lower time, before anything reaches the TPU
+    compiler (backends without the hook never refuse)."""
+    refusal = getattr(backend, "compile_refusal", None)
+    why = refusal(m, n, semantics=semantics, halo=halo) if refusal else None
+    if why:
+        raise ValueError(
+            f"backend {backend.name!r} cannot compile its kernel at m={m}, "
+            f"n={n} ({semantics}): {why}; pick another backend")
+
+
 def supported_under(backend: "StepBackend", semantics: str
                     ) -> Tuple[str, ...]:
     """``backend.supported_encodings`` under a semantics tier, tolerating
@@ -231,6 +260,9 @@ def _registry_compile(backend: "StepBackend", system: SNPSystem,
                 f"(supported encodings: {sup}); pick a backend whose "
                 "lowering supports 'sharded' there")
         return backend.lower(compile_sharded(system, plan), plan)
+    # Refuse before building: a dense encoding of a wide system is large.
+    _refuse_uncompilable(backend, system.num_neurons, system.num_rules,
+                         plan.semantics)
     enc = sup[0] if plan.encoding == "auto" else plan.encoding
     if enc not in sup:
         raise ValueError(
@@ -410,17 +442,19 @@ class RefBackend:
 class PallasBackend:
     """Fused Pallas transition kernel (decode + S·M + C in VMEM).
 
-    ``interpret=True`` (default) emulates the kernel with jittable lax ops
-    so the same code path runs on CPU; flip to False on a real TPU.  Block
+    ``interpret=None`` (default) compiles the kernel on a TPU and emulates
+    it with jittable lax ops anywhere else; a bool pins the mode.  Block
     shapes are clamped to the problem size by the ops wrapper, so the
-    defaults are safe for small systems too.  Under a sharded plan,
+    defaults are safe for small systems too.  A compiled kernel refuses
+    at lower time a system whose VMEM working set would not fit
+    (:meth:`compile_refusal`).  Under a sharded plan,
     ``lower`` attaches the dense per-shard operands
     (:func:`repro.core.plan.lower_shard_dense`) and the same kernel body
     consumes one shard per device: ``C' = C + halo·H_adj + S·M_local``.
     """
 
     name: str = "pallas"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     block_b: int = 8
     block_t: int = 32
     block_n: int = 128
@@ -446,8 +480,31 @@ class PallasBackend:
                             ) -> Tuple[str, ...]:
         return ("dense",) if semantics == "delays" else ("dense", "sharded")
 
+    def compile_refusal(self, m: int, n: int, *,
+                        semantics: str = "no_delays",
+                        halo: int = 0) -> Optional[str]:
+        """Why the compiled kernel cannot step ``m`` neurons and ``n``
+        rules at this block shape, or ``None``.  The neuron axis is not
+        tiled, so the estimated VMEM working set
+        (:func:`repro.kernels.snp_step.kernel.vmem_bytes`) must fit the
+        kernel's limit; an emulated kernel is never refused."""
+        if resolve_interpret(self.interpret):
+            return None
+        from repro.kernels.snp_step.kernel import VMEM_LIMIT_BYTES, vmem_bytes
+        need = vmem_bytes(m, n, block_b=self.block_b, block_t=self.block_t,
+                          block_n=self.block_n, halo=halo,
+                          delayed=semantics == "delays")
+        if need <= VMEM_LIMIT_BYTES:
+            return None
+        return (f"its VMEM working set at blocks (bb={self.block_b}, "
+                f"bt={self.block_t}, bn={self.block_n}) is about "
+                f"{need / 2**20:.0f} MiB, over the "
+                f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit (the neuron axis "
+                "is not tiled)")
+
     def lower(self, compiled: CompiledLike, plan: SystemPlan) -> CompiledLike:
         _check_kernel_plan(self, plan)
+        _refuse_uncompilable(self, *_kernel_shape(compiled))
         if is_sharded(compiled):
             return lower_shard_dense(compiled)
         return compiled
@@ -531,14 +588,16 @@ class SparsePallasBackend:
     same body consumes one shard per device through the extended
     ``[local | halo | zero]`` index space.
 
-    ``interpret=True`` (default) emulates the kernel on CPU; the grid is
-    ``(B/bb, T/bt)`` with the whole neuron axis resident per block, so the
-    working set is ``O(bb·bt·m)`` — the ops wrapper clamps blocks to the
-    problem size.  TPU story scales with nnz, not ``n·m``.
+    ``interpret=None`` (default) resolves from the platform like
+    :class:`PallasBackend`; the grid is ``(B/bb, T/bt)`` with the whole
+    neuron axis resident per block, so the working set is ``O(bb·bt·m)``
+    — the ops wrapper clamps blocks to the problem size.  The kernel's
+    in-VMEM gathers do not lower for a TPU yet, so every compiled lowering
+    is refused (:meth:`compile_refusal`).
     """
 
     name: str = "sparse_pallas"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     block_b: int = 8
     block_t: int = 32
     supports_nd_batch: bool = True   # flattens leading dims internally
@@ -564,8 +623,21 @@ class SparsePallasBackend:
         return ("ell", "hybrid") if semantics == "delays" \
             else ("ell", "hybrid", "sharded")
 
+    def compile_refusal(self, m: int, n: int, *,
+                        semantics: str = "no_delays",
+                        halo: int = 0) -> Optional[str]:
+        """Why the compiled kernel cannot step this shape, or ``None``:
+        every shape, until its gathers are rewritten (an emulated kernel
+        is never refused)."""
+        if resolve_interpret(self.interpret):
+            return None
+        return ("Mosaic lowers only same-shape 2-D gathers, and the kernel "
+                "gathers over its (bb, bt, m) produce rows and its "
+                "(bb, m, R) rule table")
+
     def lower(self, compiled: CompiledLike, plan: SystemPlan) -> CompiledLike:
         _check_kernel_plan(self, plan)
+        _refuse_uncompilable(self, *_kernel_shape(compiled))
         # A hybrid encoding the kernel cannot lower must raise here, at
         # lowering time — never a silent downgrade to the jnp path.  Only
         # hand-built encodings can trip this: compile_system_sparse always

@@ -60,10 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                  # jax >= 0.6 exposes it at top level
-    from jax import shard_map
-except ImportError:                   # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .backend import (BackendLike, PallasBackend, SparsePallasBackend,
                       compile_with_plan, lower_with_backend, resolve_entry,
@@ -76,8 +73,8 @@ from .hashtable import (HashTable, _base_slot, _canonical, first_occurrence,
 from .matrix import CompiledAny, is_compiled
 from .plan import (DenseShardArrays, ShardArrays, ShardedCompiled,
                    SystemPlan, compile_sharded, is_sharded, shard_view)
-from .semantics import (_decode_digits, _fired_packed, packed_rule_table,
-                        sparse_branch_info)
+from .semantics import (_decode_digits, _fired_packed, in_adjacency_sum,
+                        packed_rule_table, sparse_branch_info)
 from .system import SNPSystem
 
 __all__ = ["explore_distributed", "run_traces_distributed"]
@@ -397,10 +394,7 @@ def _sharded_body(arrs: ShardArrays, dense, carry, *, axis, ndev,
         prod_ext = jnp.concatenate(
             [prod_f, recv.reshape(F, T, S * hmax),
              jnp.zeros((F, T, 1), jnp.int32)], axis=-1)
-        delta = -cons_f
-        in_idx = arrs.in_idx[0]
-        for k in range(in_idx.shape[1]):  # static K_in, unrolled
-            delta = delta + jnp.take(prod_ext, in_idx[:, k], axis=-1)
+        delta = in_adjacency_sum(prod_ext, arrs.in_idx[0]) - cons_f
         cand = (frontier[:, None, :] + delta).reshape(K, mloc)
     valid = ((t[None, :].astype(jnp.float32) < psi[:, None])
              & alive[:, None] & fvalid[:, None]).reshape(K)
@@ -575,7 +569,7 @@ def _explore_neuron_sharded(
             mesh=mesh,
             in_specs=in_specs,
             out_specs=state_out,
-            check_rep=False,
+            check_vma=False,
         ))
 
     state = _run_fused_loop(
@@ -743,7 +737,7 @@ def explore_distributed(
             out_specs=state_in,
             # pallas_call has no replication rule; every output spec is
             # explicit anyway, so the check adds nothing here.
-            check_rep=False,
+            check_vma=False,
         ))
 
     state = _run_fused_loop(
@@ -850,5 +844,5 @@ def _traces_shard_fn(mesh, axis, steps, max_branches, policy, backend):
             out_specs=P(axis),
             # same reasoning as explore_distributed: pallas_call has no
             # replication rule, and every output spec is explicit anyway
-            check_rep=False,
+            check_vma=False,
         ))

@@ -38,6 +38,7 @@ import dataclasses
 import math
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,6 +56,7 @@ __all__ = [
     "lower_shard_dense",
     "partition_neurons",
     "partition_stats",
+    "resolve_interpret",
 ]
 
 _ENCODINGS = ("auto", "dense", "ell", "hybrid")
@@ -66,6 +68,17 @@ _PARTITIONS = ("contiguous", "degree")
 # requires spikes == 2^24, which the engine's spike-count contract
 # (DESIGN.md §2, counts < 2^24) makes unreachable.
 _NEVER_BASE = 1 << 24
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Whether a fused Pallas kernel runs emulated: ``None`` (the default
+    of the kernel backends and wrappers) means "unless JAX's default
+    backend is a TPU", so one code path compiles the kernel on a chip and
+    emulates it on the CPU.  An explicit bool pins the mode (a compile for
+    a described TPU passes ``False``)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,8 +114,10 @@ class KernelConfig:
 
     @staticmethod
     def dense_default() -> "KernelConfig":
-        """The dense wrapper defaults (``ops.snp_step``)."""
-        return KernelConfig(block_b=8, block_t=128, block_n=512)
+        """The dense wrapper defaults (``ops.snp_step``), sized so the
+        kernel's VMEM working set (``kernel.vmem_bytes``) stays inside its
+        limit up to a few thousand neurons."""
+        return KernelConfig(block_b=8, block_t=32, block_n=128)
 
     @staticmethod
     def sparse_default() -> "KernelConfig":

@@ -49,6 +49,7 @@ __all__ = [
     "spiking_vectors",
     "next_configs",
     "sparse_next_configs",
+    "in_adjacency_sum",
     "StepOut",
     "split_state",
     "delayed_branch_info",
@@ -58,6 +59,10 @@ __all__ = [
     "delayed_next_configs",
     "sparse_delayed_next_configs",
 ]
+
+# Every f32 contraction on the SNP path runs at full f32 precision, so the
+# dense steps stay bit-identical to the integer sparse path on a TPU.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def applicability(config: jnp.ndarray, comp: CompiledAny) -> jnp.ndarray:
@@ -190,15 +195,17 @@ def next_configs(
     all ``T = max_branches`` candidate branches.
     """
     S, valid, overflow = spiking_vectors(config, comp, max_branches)
-    # f32 matmul is exact for |values| < 2^24 and maps onto the MXU on TPU;
-    # spike counts beyond 2^24 are out of scope (would overflow int32 fast).
+    # An f32 matmul at HIGHEST precision is exact for |values| < 2^24 and
+    # maps onto the MXU; the TPU's default precision would round M_Π's
+    # entries (up to 2^16) to bf16.  Spike counts beyond 2^24 are out of
+    # scope (they would overflow int32 fast).
     delta = jnp.einsum(
-        "...tn,nm->...tm", S.astype(jnp.float32), comp.M.astype(jnp.float32)
-    ).astype(jnp.int32)
+        "...tn,nm->...tm", S.astype(jnp.float32), comp.M.astype(jnp.float32),
+        precision=_HIGHEST).astype(jnp.int32)
     out = config[..., None, :] + delta
     emissions = jnp.einsum(
         "...tn,n->...t", S.astype(jnp.float32),
-        comp.env_produce.astype(jnp.float32),
+        comp.env_produce.astype(jnp.float32), precision=_HIGHEST,
     ).astype(jnp.int32)
     return StepOut(configs=out, valid=valid, emissions=emissions,
                    overflow=overflow, spiking=S)
@@ -325,6 +332,37 @@ def _fired_packed(digits: jnp.ndarray, tab: jnp.ndarray) -> jnp.ndarray:
     return out.reshape(*batch, T, m)
 
 
+def in_adjacency_sum(vals: jnp.ndarray, in_idx: jnp.ndarray,
+                     coo_src: jnp.ndarray | None = None,
+                     coo_dst: jnp.ndarray | None = None) -> jnp.ndarray:
+    """``Σ_k vals[..., in_idx[j, k]]`` (plus the COO tail's segment-sum
+    into ``coo_dst``) as ``(..., m)``: the ELL in-adjacency contraction.
+    ``vals`` ``(..., W)`` already ends in the zero slot that padding
+    entries point at.
+
+    The sum runs as a ``fori_loop`` over ``K_in`` of row gathers on the
+    transposed ``(W, rows)`` layout, so one ``(m, rows)`` accumulator is
+    live whatever ``K_in`` is and the program does not grow with it.
+    Unrolled gathers along the lane axis let XLA keep every one of the
+    ``K_in`` results live: 65 GiB for a v5e's 16 at m=32768, B=256, T=32.
+    int32 throughout, so the sum is exact in any order."""
+    lead, W = vals.shape[:-1], vals.shape[-1]
+    m = in_idx.shape[0]
+    rows = vals.reshape(-1, W).T                              # (W, R)
+    cols = in_idx.T                                           # (K_in, m)
+    acc = jax.lax.fori_loop(
+        0, cols.shape[0],
+        lambda k, a: a + jnp.take(rows, cols[k], axis=0),
+        jnp.zeros((m, rows.shape[1]), vals.dtype))
+    if coo_src is not None and coo_src.shape[0]:
+        # Tail synapses of hub neurons (in-degree past the plan's hub
+        # threshold, DESIGN.md §3): gather the value at each tail source,
+        # segment-sum into the target neurons.
+        acc = acc + jax.ops.segment_sum(
+            jnp.take(rows, coo_src, axis=0), coo_dst, num_segments=m)
+    return acc.T.reshape(*lead, m)
+
+
 def sparse_next_configs(
     config: jnp.ndarray, comp: CompiledSparseSNP, max_branches: int
 ) -> StepOut:
@@ -340,7 +378,8 @@ def sparse_next_configs(
     3. contract over the ELL in-adjacency: a fired rule's row of ``M_Π`` is
        ``-consume`` at its owner plus ``produce`` on the owner's
        out-neighbors, so ``ΔC[j] = Σ_{i ∈ in(j)} produce_fired[i] -
-       consume_fired[j]`` — a ``K_in``-wide gather/segment-sum;
+       consume_fired[j]`` — a ``K_in``-wide gather/segment-sum
+       (:func:`in_adjacency_sum`);
     4. the environment emission is the fired produce at the output neuron.
 
     All arithmetic is int32 (exact); agreement with the dense f32 matmul
@@ -363,17 +402,8 @@ def sparse_next_configs(
 
     prod_pad = jnp.concatenate(
         [prod_f, jnp.zeros((B, T, 1), jnp.int32)], axis=-1)  # (B, T, m+1)
-    delta = -cons_f
-    for kk in range(comp.in_idx.shape[1]):  # static K_in, unrolled
-        delta = delta + jnp.take(prod_pad, comp.in_idx[:, kk], axis=-1)
-    if comp.coo_src.shape[0]:  # hybrid encoding: COO tail via segment-sum
-        # Tail synapses of hub neurons (in-degree past the plan's hub
-        # threshold, DESIGN.md §3): gather the fired produce at each tail
-        # source, segment-sum into the target neurons.  int32, exact.
-        contrib = jnp.take(prod_pad, comp.coo_src, axis=-1)  # (B, T, Ec)
-        tail = jax.ops.segment_sum(
-            jnp.moveaxis(contrib, -1, 0), comp.coo_dst, num_segments=m)
-        delta = delta + jnp.moveaxis(tail, 0, -1)
+    delta = in_adjacency_sum(prod_pad, comp.in_idx, comp.coo_src,
+                             comp.coo_dst) - cons_f
 
     out = cfg[:, None, :] + delta
     valid = (t[None, :].astype(jnp.float32) < info.psi[:, None]) \
@@ -486,7 +516,8 @@ def delayed_next_configs(
     (..., T, 3m) of every state row (..., 3m).
 
     The fired-rule attributes come from one stacked f32 contraction
-    ``S·W`` (:func:`delayed_weight_matrix`, exact below 2^24); the
+    ``S·W`` (:func:`delayed_weight_matrix`, exact below 2^24 at HIGHEST
+    precision); the
     reopen-pending fanout and the reception-gated incoming ride the 0/1
     synapse ``comp.adjacency``, which ``M``'s per-rule rows cannot carry.
     """
@@ -496,7 +527,8 @@ def delayed_next_configs(
     S, valid, overflow = _decode_spiking(info, comp, max_branches)
 
     acc = jnp.einsum("...tn,nk->...tk", S.astype(jnp.float32),
-                     delayed_weight_matrix(comp)).astype(jnp.int32)
+                     delayed_weight_matrix(comp),
+                     precision=_HIGHEST).astype(jnp.int32)
     cons_f = acc[..., :m]
     emit_fired = acc[..., m:2 * m]
     d_f = acc[..., 2 * m:3 * m]
@@ -506,7 +538,8 @@ def delayed_next_configs(
     emit = emit_fired + jnp.where(reopen, pd[..., None, :], 0)
     incoming = jnp.einsum(
         "...ti,ij->...tj", emit.astype(jnp.float32),
-        comp.adjacency.astype(jnp.float32)).astype(jnp.int32)
+        comp.adjacency.astype(jnp.float32),
+        precision=_HIGHEST).astype(jnp.int32)
 
     fired_del = d_f > 0
     cd_next = jnp.where(fired_del, d_f,
@@ -565,15 +598,8 @@ def sparse_delayed_next_configs(
     emit = prod_now + jnp.where(reopen, pd[:, None, :], 0)   # (B, T, m)
     emit_pad = jnp.concatenate(
         [emit, jnp.zeros((B, T, 1), jnp.int32)], axis=-1)
-    incoming = jnp.zeros((B, T, m), jnp.int32)
-    for kk in range(comp.in_idx.shape[1]):  # static K_in, unrolled
-        incoming = incoming + jnp.take(emit_pad, comp.in_idx[:, kk],
-                                       axis=-1)
-    if comp.coo_src.shape[0]:  # hybrid encoding: COO tail via segment-sum
-        contrib = jnp.take(emit_pad, comp.coo_src, axis=-1)  # (B, T, Ec)
-        tail = jax.ops.segment_sum(
-            jnp.moveaxis(contrib, -1, 0), comp.coo_dst, num_segments=m)
-        incoming = incoming + jnp.moveaxis(tail, 0, -1)
+    incoming = in_adjacency_sum(emit_pad, comp.in_idx, comp.coo_src,
+                                comp.coo_dst)
 
     cd_next = jnp.where(fired_del, d_f,
                         jnp.maximum(cd - 1, 0)[:, None, :])

@@ -38,24 +38,72 @@ combine stage applies the closed-neuron algebra (reopen-pending fanout
 over the 0/1 adjacency, reception gate, countdown/pending update) and
 writes ``(bb, bt, 3m)`` state rows ``[spikes | countdown | pending]``.
 
-TPU is the compilation *target*; correctness is validated in
-``interpret=True`` mode against :mod:`repro.kernels.snp_step.ref`.
+**Exactness on a TPU.** Every contraction runs at
+``precision=HIGHEST``: the default TPU precision rounds f32 operands to
+bf16, which is exact only for integers below 2^8, while ``M_Π`` entries
+reach 2^16.  With full-f32 passes the kernel is bit-identical to the
+integer reference for spike counts below 2^24.
+
+**VMEM.** The neuron axis is never tiled: the digit scratch and the output
+block are ``(bb, bt, m)``, the incidence and ``M`` blocks ``(m, bn)``.
+:func:`vmem_bytes` estimates one grid step's working set, and the backend
+refuses at lower time a shape whose estimate exceeds
+:data:`VMEM_LIMIT_BYTES` (``PallasBackend.compile_refusal``) instead of
+letting the TPU compiler fail at run time.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.core.plan import resolve_interpret
 
-__all__ = ["snp_step_pallas"]
+__all__ = ["snp_step_pallas", "vmem_bytes", "VMEM_LIMIT_BYTES"]
+
+# Scoped VMEM the kernel asks the TPU compiler for: most of a v5e core's
+# 128 MiB, leaving headroom for the compiler's own spills.
+VMEM_LIMIT_BYTES = 96 * 2**20
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def vmem_bytes(m: int, n: int, *, block_b: int, block_t: int, block_n: int,
+               halo: int = 0, delayed: bool = False) -> int:
+    """Estimated VMEM of one grid step at this block shape, in bytes.
+
+    Counts every block twice (the pipeline double-buffers inputs and
+    outputs), the scratch once, and the live ``(bb, bt, ·)`` temporaries
+    of the body; sub-128 lane widths are padded to 128 as the TPU lays
+    them out.  ``n`` only clamps the rule tile, as the ops wrapper does.
+    The estimate is deliberately high: at the default blocks a described
+    v5e compiles m = 11264 (estimate 102 MiB) and runs out of VMEM at
+    m = 32768 with 273 MiB used (estimate 296 MiB)."""
+    lane = lambda x: -(-x // 128) * 128  # noqa: E731
+    bb, bt = block_b, block_t
+    bn = min(block_n, lane(n))
+    w = 4 * m if delayed else m              # width of M (W under delays)
+    out_w = 3 * m if delayed else m
+    blocks = (3 * bb * lane(m) + 2 * bb * bn + bb * 128   # c/stride/choices,
+              + m * bn + bn * lane(w)                     # rank/app, psi,
+              + bb * bt * lane(out_w) + 2 * bb * bt * 128)  # onehot, M, outs
+    if not delayed:
+        blocks += bn * 128                                 # env
+    if halo:
+        blocks += bb * bt * lane(halo) + halo * lane(m)
+    if delayed:
+        blocks += 2 * bb * lane(m) + m * lane(m) + m * 128  # cd, pd, adj, outoh
+    scratch = bb * bt * lane(m) + (bb * bt * lane(w) if delayed else 0)
+    temps = 3 * bb * bt * lane(w) + 2 * bb * bt * bn
+    if delayed:
+        temps += 8 * bb * bt * lane(m)       # the combine stage's rows
+    # 1/8 margin over the count: the compiler's own spills and padding
+    return 4 * (2 * blocks + scratch + temps) * 9 // 8
 
 
 def _make_kernel(has_halo: bool, has_delay: bool = False):
@@ -87,8 +135,8 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
             adj_ref = next(it)   # (m, m)  f32 — 0/1 synapse adjacency
             outoh_ref = next(it)  # (m, 1) f32 — output-neuron one-hot
         out_ref = next(it)      # (bb, bt, m|3m) f32 — accumulated over k
-        valid_ref = next(it)    # (bb, bt) i32
-        emis_ref = next(it)     # (bb, bt) f32 (accumulated over k)
+        valid_ref = next(it)    # (bb, bt, 1) i32
+        emis_ref = next(it)     # (bb, bt, 1) f32 (accumulated over k)
         digit_ref = next(it)    # (bb, bt, m) f32 scratch, persists across k
         if has_delay:
             acc_ref = next(it)  # (bb, bt, 4m) f32 scratch — S·W accumulator
@@ -118,11 +166,13 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
                         halo_ref[...], hadj_ref[...],
                         (((2,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32,
+                        precision=_HIGHEST,
                     )
                 out_ref[...] = base
-            emis_ref[...] = jnp.zeros((bb, bt), jnp.float32)
-            tf = t.reshape(1, bt).astype(jnp.float32)
-            valid_ref[...] = (tf < psi_ref[...]).astype(jnp.int32)
+            emis_ref[...] = jnp.zeros((bb, bt, 1), jnp.float32)
+            valid_ref[...] = (t.astype(jnp.float32)
+                              < psi_ref[...].reshape(bb, 1, 1)
+                              ).astype(jnp.int32)
 
         digits = digit_ref[...]                               # (bb, bt, m)
         # "gather digit of each rule's neuron" as an MXU matmul with the
@@ -131,6 +181,7 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
             digits, onehot_ref[...],
             (((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=_HIGHEST,
         )                                                     # (bb, bt, bn)
         s = app_ref[...].reshape(bb, 1, -1) * (
             digits_r == rank_ref[...].reshape(bb, 1, -1)
@@ -140,12 +191,14 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
                 s, mat_ref[...],
                 (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=_HIGHEST,
             )
             emis_ref[...] += jax.lax.dot_general(
                 s, env_ref[...],
                 (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ).reshape(bb, bt)
+                precision=_HIGHEST,
+            )
             return
 
         # Delayed tier: accumulate the stacked contraction S·W — per
@@ -156,6 +209,7 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
             s, mat_ref[...],
             (((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=_HIGHEST,
         )
 
         @pl.when(k == pl.num_programs(2) - 1)
@@ -174,6 +228,7 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
                 emit, adj_ref[...],
                 (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=_HIGHEST,
             )
             fired_del = d_f > 0.0
             cd_next = jnp.where(fired_del, d_f, jnp.maximum(cd - 1.0, 0.0))
@@ -188,7 +243,8 @@ def _make_kernel(has_halo: bool, has_delay: bool = False):
                 emit, outoh_ref[...],
                 (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ).reshape(bb, bt)
+                precision=_HIGHEST,
+            )
 
     return kernel
 
@@ -219,7 +275,7 @@ def snp_step_pallas(
     block_b: int,
     block_t: int,
     block_n: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Raw tiled kernel call.  Use :mod:`..ops` for the padded public API
     — the block shape is *required* here: the grid/tile choice belongs to
@@ -229,7 +285,14 @@ def snp_step_pallas(
     ``cd``/``pd``/``adj``/``outoh`` select the delayed-semantics body,
     with ``M`` carrying the stacked (n, 4m) weight matrix
     (:func:`repro.core.semantics.delayed_weight_matrix`) and the output
-    widening to ``(B, T, 3m)`` state rows."""
+    widening to ``(B, T, 3m)`` state rows.  ``interpret=None`` emulates
+    the kernel unless JAX's default backend is a TPU
+    (:func:`repro.core.plan.resolve_interpret`).
+
+    ``valid``/``emissions`` leave the kernel as ``(B, T, 1)`` blocks, so
+    a ``block_t`` below ``T`` only has to be a multiple of 8, not of the
+    128-lane width a trailing ``(bb, bt)`` block would need."""
+    interpret = resolve_interpret(interpret)
     B, m = configs.shape
     n = rank.shape[1]
     T = max_branches
@@ -299,18 +362,20 @@ def snp_step_pallas(
         out_specs=[
             pl.BlockSpec((block_b, block_t, out_m),
                          lambda i, j, k: (i, j, 0)),
-            pl.BlockSpec((block_b, block_t), lambda i, j, k: (i, j)),
-            pl.BlockSpec((block_b, block_t), lambda i, j, k: (i, j)),
+            pl.BlockSpec((block_b, block_t, 1), lambda i, j, k: (i, j, 0)),
+            pl.BlockSpec((block_b, block_t, 1), lambda i, j, k: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, out_m), jnp.float32),
-            jax.ShapeDtypeStruct((B, T), jnp.int32),
-            jax.ShapeDtypeStruct((B, T), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, T, 1), jnp.float32),
         ],
         scratch_shapes=scratch_shapes,
-        compiler_params=None if interpret else _CompilerParams(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*operands)
-    return out.astype(jnp.int32), valid.astype(bool), emis.astype(jnp.int32)
+    return (out.astype(jnp.int32), valid[..., 0].astype(bool),
+            emis[..., 0].astype(jnp.int32))

@@ -12,7 +12,8 @@ through the same kernel body's halo form (``C' = C + halo·H_adj +
 S·M_local`` — DESIGN.md §3 "Kernel lowering"), with the bookkeeping and
 the halo exchange owned by ``explore_distributed``'s sharded step.
 
-On CPU the kernel runs in interpret mode; on TPU pass ``interpret=False``.
+``interpret=None`` (the default) compiles the kernel on a TPU and emulates
+it anywhere else (:func:`repro.core.plan.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -76,7 +77,7 @@ def snp_step(
     block_t: Optional[int] = None,
     block_n: Optional[int] = None,
     kernel: Optional[KernelConfig] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused successor expansion: returns (successors (B,T,m) int32,
     valid (B,T) bool, emissions (B,T) int32, overflow (B,) bool).
@@ -171,7 +172,7 @@ def snp_step_dense_shard(
     block_t: Optional[int] = None,
     block_n: Optional[int] = None,
     kernel: Optional[KernelConfig] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """One shard's candidate slices ``(B, T, mloc)`` through the fused
     dense kernel (``C' = C + halo·H_adj + S·M_local`` — kernel.py module

@@ -44,26 +44,27 @@ metadata, both optional and both scatter-free:
 
 Grid: ``(B/bb, T/bt)`` with the whole neuron axis resident per block; the
 VMEM working set is ``O(bb·bt·(m + H + Ec))``, so the ops wrapper shrinks
-``bb`` for very wide systems.  All arithmetic is int32 (exact).  TPU is
-the compilation *target*; correctness is validated in ``interpret=True``
-mode against :func:`repro.core.semantics.sparse_next_configs` (the
-in-kernel gathers lower to Mosaic dynamic-gathers on real hardware —
-revalidate bit-for-bit on a TPU before flipping ``interpret=False`` in
-production, see ROADMAP).
+``bb`` for very wide systems.  All arithmetic is int32 (exact).
+Correctness is validated in interpret mode against
+:func:`repro.core.semantics.sparse_next_configs`.  The kernel does not
+compile for a TPU yet: Mosaic lowers only same-shape 2-D gathers, and the
+body gathers over the ``(bb, bt, m)`` produce rows and the ``(bb, m, R)``
+rule table.  ``SparsePallasBackend`` therefore refuses every compiled
+(non-interpret) lowering at lower time, and the planner never picks it on
+a chip.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.core.plan import resolve_interpret
 
 __all__ = ["snp_step_sparse_pallas"]
 
@@ -96,8 +97,8 @@ def _make_kernel(has_coo: bool, has_halo: bool, has_delay: bool = False):
             cd_ref = next(it)       # (bb, m)    i32 — countdowns
             pd_ref = next(it)       # (bb, m)    i32 — pending spikes
         out_ref = next(it)      # (bb, bt, m|3m) i32 — successor configs
-        valid_ref = next(it)    # (bb, bt)    i32
-        emis_ref = next(it)     # (bb, bt)    i32
+        valid_ref = next(it)    # (bb, bt, 1) i32
+        emis_ref = next(it)     # (bb, bt, 1) i32
 
         j = pl.program_id(1)   # branch-tile index
         bb, bt, _ = out_ref.shape
@@ -183,9 +184,10 @@ def _make_kernel(has_coo: bool, has_halo: bool, has_delay: bool = False):
                                 jnp.where(reopen, 0, pd))
             out_ref[...] = jnp.concatenate(
                 [spikes, cd_next, pd_next], axis=-1)
-        tfv = t.reshape(1, bt).astype(jnp.float32)
-        valid_ref[...] = (tfv < psi_ref[...]).astype(jnp.int32)
-        emis_ref[...] = jnp.take(prod_ext, outn_ref[0], axis=-1)
+        valid_ref[...] = (tf < psi_ref[...].reshape(bb, 1, 1)
+                          ).astype(jnp.int32)
+        emis_ref[...] = jnp.take(prod_ext, outn_ref[0], axis=-1
+                                 ).reshape(bb, bt, 1)
 
     return kernel
 
@@ -213,7 +215,7 @@ def snp_step_sparse_pallas(
     max_branches: int,
     block_b: int,
     block_t: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Raw tiled kernel call.  Use :mod:`..sparse_ops` for the padded
     public API — the block shape is *required* here: the grid/tile choice
@@ -224,7 +226,10 @@ def snp_step_sparse_pallas(
     extended-index shard stage — both default to the pure-ELL body.
     ``dtab``/``cd``/``pd`` select the delayed-semantics body (``tab``
     then carries the emit-now payload ``packed_e``) and the output widens
-    to ``(B, T, 3m)`` state rows."""
+    to ``(B, T, 3m)`` state rows.  ``valid``/``emissions`` leave the
+    kernel as ``(B, T, 1)`` blocks (the dense kernel's layout), so
+    ``block_t`` need only be a multiple of 8."""
+    interpret = resolve_interpret(interpret)
     B, m = configs.shape
     R = tab.shape[2]
     Kin = in_idx.shape[1]
@@ -289,17 +294,17 @@ def snp_step_sparse_pallas(
         out_specs=[
             pl.BlockSpec((block_b, block_t, out_m),
                          lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
+            pl.BlockSpec((block_b, block_t, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((block_b, block_t, 1), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, out_m), jnp.int32),
-            jax.ShapeDtypeStruct((B, T), jnp.int32),
-            jax.ShapeDtypeStruct((B, T), jnp.int32),
+            jax.ShapeDtypeStruct((B, T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, T, 1), jnp.int32),
         ],
-        compiler_params=None if interpret else _CompilerParams(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
     )(*operands)
-    return out, valid.astype(bool), emis
+    return out, valid[..., 0].astype(bool), emis[..., 0]

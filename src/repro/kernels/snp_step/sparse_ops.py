@@ -21,7 +21,8 @@ Two entry points over the one encoding-parameterized kernel body
   the extended ``[local | halo | zero]`` space.  Traceable inside
   ``shard_map`` — the halo ``all_to_all`` stays outside the kernel.
 
-On CPU the kernels run in interpret mode; on TPU pass ``interpret=False``.
+``interpret=None`` (the default) compiles the kernels on a TPU and emulates
+them anywhere else (:func:`repro.core.plan.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -89,7 +90,7 @@ def snp_step_sparse(
     block_b: Optional[int] = None,
     block_t: Optional[int] = None,
     kernel: Optional[KernelConfig] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused sparse successor expansion: returns (successors (B,T,m) int32,
     valid (B,T) bool, emissions (B,T) int32, overflow (B,) bool).
@@ -180,7 +181,7 @@ def snp_step_sparse_shard(
     block_b: Optional[int] = None,
     block_t: Optional[int] = None,
     kernel: Optional[KernelConfig] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """One shard's candidate slices ``(B, T, mloc)`` through the fused
     kernel.  Bookkeeping (branch info, radix combine, the halo exchange)

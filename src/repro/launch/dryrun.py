@@ -157,11 +157,12 @@ def run_snp_cell(multi_pod: bool, *, neurons: int = 2048, rules: int = 4096,
     """Dry-run of the distributed SNP exploration step on the production
     mesh (the paper's workload at 'very large system' scale)."""
     import functools
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core.backend import get_backend
-    from repro.core.distributed import _device_step, shard_map
+    from repro.core.distributed import _dense_loop
     from repro.core.generators import random_system
+    from repro.core.hashtable import table_slots
     from repro.core.matrix import compile_system
 
     t0 = time.time()
@@ -174,28 +175,34 @@ def run_snp_cell(multi_pod: bool, *, neurons: int = 2048, rules: int = 4096,
     m, n = comp.num_neurons, comp.num_rules
     F, T = frontier_per_dev, max_branches
     C = max(16, (F * T) // ndev)
+    V = 4096
+    SL = table_slots(V)
 
+    # The fused BFS loop explore_distributed runs: the carry is the
+    # device-sharded state plus the replicated (step, total_new) scalars,
+    # and the last argument is the replicated level bound.
+    state_specs = (P("x"),) * 9 + (P(), P())
     step = jax.jit(
-        shard_map(
-            functools.partial(_device_step, axis="x", ndev=ndev,
-                              max_branches=T, send_cap=C,
+        jax.shard_map(
+            functools.partial(_dense_loop, axis="x", ndev=ndev,
+                              max_branches=T, send_cap=C, visited_cap=V,
                               backend=get_backend("ref")),
             mesh=flat,
-            in_specs=(P(), P("x"), P("x"), P("x"), P("x"), P("x"), P("x"),
-                      P("x")),
-            out_specs=(P("x"), P("x"), P("x"), P("x"), P("x"), P("x"),
-                       P("x"), P()),
+            in_specs=(P(),) + state_specs + (P(),),
+            out_specs=state_specs,
+            check_vma=False,
         ),
-        donate_argnums=(1, 2, 3, 4, 5, 6, 7),
+        donate_argnums=tuple(range(1, 12)),
     )
-    V = 4096
     sds = jax.ShapeDtypeStruct
     args = (
         jax.eval_shape(lambda: comp),
         sds((ndev * F, m), jnp.int32), sds((ndev * F,), jnp.bool_),
-        sds((ndev * V,), jnp.uint32), sds((ndev * V,), jnp.uint32),
+        sds((ndev * SL,), jnp.uint32), sds((ndev * SL,), jnp.uint32),
+        sds((ndev * SL,), jnp.int32), sds((ndev,), jnp.int32),
         sds((ndev * V, m), jnp.int32), sds((ndev,), jnp.int32),
-        sds((ndev, 3), jnp.bool_),
+        sds((ndev, 3), jnp.bool_), sds((), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32),
     )
     with flat:
         lowered = step.lower(*args)

@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.smoke import reduced
 from repro.data import DataConfig, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build_mesh_for_available
 from repro.models import init_params
 from repro.serve import (SNPTraceService, TraceRequest, make_decode_step,
@@ -211,6 +212,7 @@ def main(argv=None):
                     help="deterministic fault schedule, e.g. "
                          "'fail=2,4 poison=17 slow=3:0.05'")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.batch is None:
         args.batch = 256 if args.snp else 4

@@ -255,3 +255,27 @@ def test_sharded_planning_picks_sharded_capable_backend(cache_file):
         assert plan.encoding == "ell" and plan.num_shards == 2
         assert "sharded" in \
             get_backend(plan.backend).supported_encodings()
+
+
+def test_planner_skips_kernels_a_chip_refuses(cache_file, monkeypatch):
+    """With the kernels compiled instead of emulated (as on a TPU), the
+    planner never hands out a backend whose ``compile_refusal`` names the
+    shape — so a refusal never reaches the degrade chain at run time."""
+    import repro.core.backend as backend_mod
+    from repro.core import add_degrade_listener, paper_pi, \
+        remove_degrade_listener
+    monkeypatch.setattr(backend_mod, "resolve_interpret", lambda flag: False)
+    system = paper_pi(covering=True)
+    for workload in ((128, 16), (256, 64), (64, 32)):
+        plan = SystemPlan.for_system(system, workload=workload, mode="auto")
+        be = get_backend(plan.backend)
+        assert getattr(be, "compile_refusal",
+                       lambda *a, **k: None)(3, 5) is None, plan
+    events = []
+    add_degrade_listener(events.append)
+    try:
+        res = explore(system, max_steps=8, frontier_cap=128,
+                      visited_cap=1024, max_branches=16)
+    finally:
+        remove_degrade_listener(events.append)
+    assert not events and res.num_discovered > 1

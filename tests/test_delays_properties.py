@@ -151,7 +151,7 @@ def test_zero_delay_is_bit_identical_to_no_delays(system):
     cfg = jnp.asarray(system.initial_spikes, jnp.int32)
     m = system.num_neurons
     from repro.core.semantics import next_configs
-    base = next_configs(cfg, compile_system(system), T)
+    base = next_configs(cfg, compile_system(sys0), T)
     want = _rows(base.configs, base.valid, base.emissions)
     state = jnp.concatenate([cfg, jnp.zeros(2 * m, jnp.int32)])
     o = delayed_next_configs(state,
