@@ -57,7 +57,7 @@ def _bench_sync(system, n, steps, batch, runner=None, tag="sync"):
     dt = time.perf_counter() - t0
     assert len(results) == n
     return (f"serve/{tag}/pi_N{n}_s{steps}_b{batch}", dt / n * 1e6,
-            f"{n / dt:.0f}tr/s,{svc.num_device_calls - 1}calls")
+            f"{n / dt:.0f}tr/s,{svc.stats()['device_calls'] - 1}calls")
 
 
 def _bench_async(system, n, steps, batch, max_delay_ms):

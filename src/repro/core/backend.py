@@ -66,6 +66,7 @@ from dataclasses import dataclass
 from typing import Dict, Optional, Protocol, Tuple, Union, runtime_checkable
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from .matrix import (CompiledAny, CompiledSNP, CompiledSparseSNP,
                      compile_system, compile_system_sparse, is_delayed)
@@ -285,9 +286,10 @@ def compile_with_plan(backend: "StepBackend", system: SNPSystem,
     backends that predate the plan parameter (they only ever see the
     default plan, which is the identity — the entry points always carry a
     plan now, so the identity check matters, not just ``None``)."""
-    if plan is None or plan == SystemPlan():
-        return backend.compile(system)
-    return backend.compile(system, plan=plan)
+    with TraceAnnotation("snp.lower"):
+        if plan is None or plan == SystemPlan():
+            return backend.compile(system)
+        return backend.compile(system, plan=plan)
 
 
 def lower_with_backend(backend: "StepBackend", compiled: CompiledLike,
@@ -297,7 +299,8 @@ def lower_with_backend(backend: "StepBackend", compiled: CompiledLike,
     lower = getattr(backend, "lower", None)
     if lower is None:
         return compiled
-    return lower(compiled, _plan_or_default(plan))
+    with TraceAnnotation("snp.lower"):
+        return lower(compiled, _plan_or_default(plan))
 
 
 def _check_kernel_plan(backend: "StepBackend", plan: SystemPlan) -> None:
@@ -350,25 +353,26 @@ def resolve_entry_info(system, backend: Optional["BackendLike"],
     gracefully degrade down :data:`repro.core.failover.DEGRADE_ORDER`)
     and False when the caller pinned it by name or plan (pinning is a
     contract — a pinned backend's failure raises)."""
-    plan = _plan_or_default(plan)
-    planned = False
-    if backend is None:
-        if (plan.backend is None and plan.mode in ("auto", "measure")
-                and plan.encoding == "auto" and plan.kernel is None
-                and isinstance(system, SNPSystem)):
-            plan = SystemPlan.for_system(
-                system, num_shards=plan.num_shards, workload=workload,
-                mode=plan.mode, semantics=plan.semantics)
-            planned = True
-        name = plan.backend
-        if name is None:
-            name = "sparse" if isinstance(system, CompiledSparseSNP) \
-                else "ref"
-            planned = False
-        be = get_backend(name)
-    else:
-        be = get_backend(backend)
-    return resolve_kernel(be, plan), plan, planned
+    with TraceAnnotation("snp.plan"):
+        plan = _plan_or_default(plan)
+        planned = False
+        if backend is None:
+            if (plan.backend is None and plan.mode in ("auto", "measure")
+                    and plan.encoding == "auto" and plan.kernel is None
+                    and isinstance(system, SNPSystem)):
+                plan = SystemPlan.for_system(
+                    system, num_shards=plan.num_shards, workload=workload,
+                    mode=plan.mode, semantics=plan.semantics)
+                planned = True
+            name = plan.backend
+            if name is None:
+                name = "sparse" if isinstance(system, CompiledSparseSNP) \
+                    else "ref"
+                planned = False
+            be = get_backend(name)
+        else:
+            be = get_backend(backend)
+        return resolve_kernel(be, plan), plan, planned
 
 
 def resolve_entry(system, backend: Optional["BackendLike"],

@@ -58,6 +58,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
@@ -540,7 +541,9 @@ def _explore_neuron_sharded(
         init_g = jnp.concatenate(
             [jnp.asarray(init, jnp.int32), jnp.zeros((pad,), jnp.int32)])
         init_cols = init_g[gidx]
-    state = jax.jit(_init, out_shardings=state_shardings)(init_cols, gidx)
+    with TraceAnnotation("snp.explore.init"):
+        state = jax.jit(_init, out_shardings=state_shardings)(init_cols,
+                                                              gidx)
 
     kw = dict(axis=axis, ndev=S, mloc=mloc, hmax=comp.halo_width,
               max_branches=T, visited_cap=V, backend=backend)
@@ -577,29 +580,32 @@ def _explore_neuron_sharded(
         checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
         fault_injector=fault_injector)
 
-    (_, _, _, _, _, _, archive, archive_n, flags, step,
-     total_new) = jax.device_get(state)
-    n = int(archive_n)
-    if n:
-        # columns back to global neuron order via the partition's
-        # column→neuron map (identity for contiguous shards)
-        cols = np.concatenate(list(archive.reshape(S, A, mloc)),
-                              axis=1)[:n]
-        configs = np.zeros((n, S * mloc), np.int32)
-        configs[:, jax.device_get(gidx)] = cols
-        configs = configs[:, :m]
-    else:
-        configs = np.zeros((0, m), np.int32)
-    flags = flags.reshape(S, 3).any(axis=0)
-    return ExploreResult(
-        configs=configs,
-        num_discovered=n,
-        steps=int(step),
-        exhausted=int(total_new) == 0 and not flags.any(),
-        branch_overflow=bool(flags[0]),
-        frontier_overflow=bool(flags[1]),
-        visited_overflow=bool(flags[2]),
-    )
+    with TraceAnnotation("snp.explore.wait"):
+        jax.block_until_ready(state)
+    with TraceAnnotation("snp.explore.readback"):
+        (_, _, _, _, _, _, archive, archive_n, flags, step,
+         total_new) = jax.device_get(state)
+        n = int(archive_n)
+        if n:
+            # columns back to global neuron order via the partition's
+            # column→neuron map (identity for contiguous shards)
+            cols = np.concatenate(list(archive.reshape(S, A, mloc)),
+                                  axis=1)[:n]
+            configs = np.zeros((n, S * mloc), np.int32)
+            configs[:, jax.device_get(gidx)] = cols
+            configs = configs[:, :m]
+        else:
+            configs = np.zeros((0, m), np.int32)
+        flags = flags.reshape(S, 3).any(axis=0)
+        return ExploreResult(
+            configs=configs,
+            num_discovered=n,
+            steps=int(step),
+            exhausted=int(total_new) == 0 and not flags.any(),
+            branch_overflow=bool(flags[0]),
+            frontier_overflow=bool(flags[1]),
+            visited_overflow=bool(flags[2]),
+        )
 
 
 def explore_distributed(
@@ -651,115 +657,124 @@ def explore_distributed(
     under the default ``SystemPlan(mode="auto")``, exactly like the
     single-device :func:`~repro.core.engine.explore` — the planner only
     picks sharded-capable backends when ``plan.num_shards > 1``."""
-    mesh, axis = _flat_mesh(mesh)
-    ndev = mesh.devices.size
-    # resolve_entry also folds plan.kernel into the backend instance, and
-    # the backend instance is what keys every downstream executable cache
-    # (jit static args here, _traces_shard_fn's lru key below) — so two
-    # block configurations can never collide into one cached executable.
-    be, plan = resolve_entry(system, backend, plan,
-                             workload=(frontier_cap, max_branches))
-    sharded_plan = plan.num_shards > 1
-    if is_sharded(system) or sharded_plan:
-        if is_sharded(system):
-            comp = system
-        else:
-            if not isinstance(system, SNPSystem):
+    with TraceAnnotation("snp.explore"):
+        mesh, axis = _flat_mesh(mesh)
+        ndev = mesh.devices.size
+        # resolve_entry also folds plan.kernel into the backend instance, and
+        # the backend instance is what keys every downstream executable cache
+        # (jit static args here, _traces_shard_fn's lru key below) — so two
+        # block configurations can never collide into one cached executable.
+        be, plan = resolve_entry(system, backend, plan,
+                                 workload=(frontier_cap, max_branches))
+        sharded_plan = plan.num_shards > 1
+        if is_sharded(system) or sharded_plan:
+            if is_sharded(system):
+                comp = system
+            else:
+                if not isinstance(system, SNPSystem):
+                    raise ValueError(
+                        "neuron-axis sharded exploration needs the SNPSystem "
+                        "(or a pre-lowered ShardedCompiled), not a single-"
+                        f"device encoding ({type(system).__name__})")
+                comp = compile_sharded(system, plan)
+            if comp.num_shards != ndev:
                 raise ValueError(
-                    "neuron-axis sharded exploration needs the SNPSystem "
-                    "(or a pre-lowered ShardedCompiled), not a single-"
-                    f"device encoding ({type(system).__name__})")
-            comp = compile_sharded(system, plan)
-        if comp.num_shards != ndev:
-            raise ValueError(
-                f"plan.num_shards ({comp.num_shards}) must equal the mesh "
-                f"device count ({ndev}); build the plan with "
-                "sharding.specs.neuron_axis(ndev)")
-        if not supports_sharded(be):
-            raise ValueError(
-                f"backend {be.name!r} does not declare the 'sharded' "
-                "encoding in its lowering registry "
-                "(StepBackend.supported_encodings), so it cannot step a "
-                "neuron shard; every built-in backend supports it")
-        comp = lower_with_backend(be, comp, comp.plan)
-        return _explore_neuron_sharded(
-            comp, mesh, axis, be, max_steps=max_steps,
-            frontier_cap=frontier_cap, visited_cap=visited_cap,
-            max_branches=max_branches, init=init,
+                    f"plan.num_shards ({comp.num_shards}) must equal the mesh "
+                    f"device count ({ndev}); build the plan with "
+                    "sharding.specs.neuron_axis(ndev)")
+            if not supports_sharded(be):
+                raise ValueError(
+                    f"backend {be.name!r} does not declare the 'sharded' "
+                    "encoding in its lowering registry "
+                    "(StepBackend.supported_encodings), so it cannot step a "
+                    "neuron shard; every built-in backend supports it")
+            comp = lower_with_backend(be, comp, comp.plan)
+            return _explore_neuron_sharded(
+                comp, mesh, axis, be, max_steps=max_steps,
+                frontier_cap=frontier_cap, visited_cap=visited_cap,
+                max_branches=max_branches, init=init,
+                checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every,
+                fault_injector=fault_injector)
+        comp = lower_with_backend(be, system, plan) if is_compiled(system) \
+            else compile_with_plan(be, system, plan)
+        m = comp.num_neurons
+        F, V, T = frontier_cap, visited_cap, max_branches
+        C = send_cap if send_cap is not None \
+            else max(16, (F * T) // max(ndev, 1))
+
+        SL = table_slots(V)
+        c0 = comp.init_config if init is None else jnp.asarray(init, jnp.int32)
+
+        # global state, sharded on the leading device axis; everything is
+        # allocated (and the init config hashed + table-inserted) inside one
+        # jitted init — no host-side O(ndev·V) arrays, no host hashing.
+        shard = NamedSharding(mesh, P(axis))
+        repl = NamedSharding(mesh, P())
+
+        def _init(c0):
+            hi0, lo0 = config_hash(c0)
+            hic, loc = _canonical(hi0[None], lo0[None], jnp.ones((1,), bool))
+            owner0 = (hic[0] % np.uint32(ndev)).astype(jnp.int32)
+            base0 = _base_slot(hic, loc, SL).astype(jnp.int32)[0]
+            frontier = jnp.zeros((ndev * F, m), jnp.int32).at[
+                owner0 * F].set(c0)
+            fvalid = jnp.zeros((ndev * F,), bool).at[owner0 * F].set(True)
+            vhi = jnp.full((ndev * SL,), SENTINEL, jnp.uint32).at[
+                owner0 * SL + base0].set(hic[0])
+            vlo = jnp.full((ndev * SL,), SENTINEL, jnp.uint32).at[
+                owner0 * SL + base0].set(loc[0])
+            vpay = jnp.full((ndev * SL,), -1, jnp.int32).at[
+                owner0 * SL + base0].set(0)
+            vcount = jnp.zeros((ndev,), jnp.int32).at[owner0].set(1)
+            archive = jnp.zeros((ndev * V, m), jnp.int32).at[
+                owner0 * V].set(c0)
+            arch_n = jnp.zeros((ndev,), jnp.int32).at[owner0].set(1)
+            return (frontier, fvalid, vhi, vlo, vpay, vcount, archive, arch_n,
+                    jnp.zeros((ndev, 3), bool), jnp.asarray(0, jnp.int32),
+                    jnp.asarray(1, jnp.int32))
+
+        state_shardings = (shard,) * 9 + (repl, repl)
+        with TraceAnnotation("snp.explore.init"):
+            state = jax.jit(_init, out_shardings=state_shardings)(c0)
+
+        state_in = (P(axis),) * 9 + (P(), P())
+        loop_fn = jax.jit(
+            shard_map(
+                functools.partial(_dense_loop, axis=axis, ndev=ndev,
+                                  max_branches=T, send_cap=C, visited_cap=V,
+                                  backend=be),
+                mesh=mesh,
+                in_specs=(P(),) + state_in + (P(),),
+                out_specs=state_in,
+                # pallas_call has no replication rule; every output spec is
+                # explicit anyway, so the check adds nothing here.
+                check_vma=False,
+            ))
+
+        state = _run_fused_loop(
+            loop_fn, (comp,), state, max_steps=max_steps,
             checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
             fault_injector=fault_injector)
-    comp = lower_with_backend(be, system, plan) if is_compiled(system) \
-        else compile_with_plan(be, system, plan)
-    m = comp.num_neurons
-    F, V, T = frontier_cap, visited_cap, max_branches
-    C = send_cap if send_cap is not None else max(16, (F * T) // max(ndev, 1))
 
-    SL = table_slots(V)
-    c0 = comp.init_config if init is None else jnp.asarray(init, jnp.int32)
-
-    # global state, sharded on the leading device axis; everything is
-    # allocated (and the init config hashed + table-inserted) inside one
-    # jitted init — no host-side O(ndev·V) arrays, no host hashing.
-    shard = NamedSharding(mesh, P(axis))
-    repl = NamedSharding(mesh, P())
-
-    def _init(c0):
-        hi0, lo0 = config_hash(c0)
-        hic, loc = _canonical(hi0[None], lo0[None], jnp.ones((1,), bool))
-        owner0 = (hic[0] % np.uint32(ndev)).astype(jnp.int32)
-        base0 = _base_slot(hic, loc, SL).astype(jnp.int32)[0]
-        frontier = jnp.zeros((ndev * F, m), jnp.int32).at[owner0 * F].set(c0)
-        fvalid = jnp.zeros((ndev * F,), bool).at[owner0 * F].set(True)
-        vhi = jnp.full((ndev * SL,), SENTINEL, jnp.uint32).at[
-            owner0 * SL + base0].set(hic[0])
-        vlo = jnp.full((ndev * SL,), SENTINEL, jnp.uint32).at[
-            owner0 * SL + base0].set(loc[0])
-        vpay = jnp.full((ndev * SL,), -1, jnp.int32).at[
-            owner0 * SL + base0].set(0)
-        vcount = jnp.zeros((ndev,), jnp.int32).at[owner0].set(1)
-        archive = jnp.zeros((ndev * V, m), jnp.int32).at[owner0 * V].set(c0)
-        arch_n = jnp.zeros((ndev,), jnp.int32).at[owner0].set(1)
-        return (frontier, fvalid, vhi, vlo, vpay, vcount, archive, arch_n,
-                jnp.zeros((ndev, 3), bool), jnp.asarray(0, jnp.int32),
-                jnp.asarray(1, jnp.int32))
-
-    state_shardings = (shard,) * 9 + (repl, repl)
-    state = jax.jit(_init, out_shardings=state_shardings)(c0)
-
-    state_in = (P(axis),) * 9 + (P(), P())
-    loop_fn = jax.jit(
-        shard_map(
-            functools.partial(_dense_loop, axis=axis, ndev=ndev,
-                              max_branches=T, send_cap=C, visited_cap=V,
-                              backend=be),
-            mesh=mesh,
-            in_specs=(P(),) + state_in + (P(),),
-            out_specs=state_in,
-            # pallas_call has no replication rule; every output spec is
-            # explicit anyway, so the check adds nothing here.
-            check_vma=False,
-        ))
-
-    state = _run_fused_loop(
-        loop_fn, (comp,), state, max_steps=max_steps,
-        checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-        fault_injector=fault_injector)
-
-    (_, _, _, _, _, _, archive, arch_n, flags, step,
-     total_new) = jax.device_get(state)
-    configs = np.concatenate([
-        archive[d * V: d * V + int(arch_n[d])] for d in range(ndev)
-    ]) if arch_n.sum() else np.zeros((0, m), np.int32)
-    flags = flags.reshape(ndev, 3).any(axis=0)
-    return ExploreResult(
-        configs=configs,
-        num_discovered=int(arch_n.sum()),
-        steps=int(step),
-        exhausted=int(total_new) == 0 and not flags.any(),
-        branch_overflow=bool(flags[0]),
-        frontier_overflow=bool(flags[1]),
-        visited_overflow=bool(flags[2]),
-    )
+        with TraceAnnotation("snp.explore.wait"):
+            jax.block_until_ready(state)
+        with TraceAnnotation("snp.explore.readback"):
+            (_, _, _, _, _, _, archive, arch_n, flags, step,
+             total_new) = jax.device_get(state)
+            configs = np.concatenate([
+                archive[d * V: d * V + int(arch_n[d])] for d in range(ndev)
+            ]) if arch_n.sum() else np.zeros((0, m), np.int32)
+            flags = flags.reshape(ndev, 3).any(axis=0)
+            return ExploreResult(
+                configs=configs,
+                num_discovered=int(arch_n.sum()),
+                steps=int(step),
+                exhausted=int(total_new) == 0 and not flags.any(),
+                branch_overflow=bool(flags[0]),
+                frontier_overflow=bool(flags[1]),
+                visited_overflow=bool(flags[2]),
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -803,29 +818,33 @@ def run_traces_distributed(
     # The planner decides when backend=None (default SystemPlan mode
     # "auto"); _traces_shard_fn's lru cache keys on the resolved backend
     # *instance*, so a plan kernel's block shape is part of the key.
-    be, plan, planned = resolve_entry_info(
-        system, backend, plan, workload=(int(seeds.shape[0]), max_branches))
-    mesh, axis = _flat_mesh(mesh)
-    ndev = mesh.devices.size
+    with TraceAnnotation("snp.traces", batch=int(seeds.shape[0])):
+        be, plan, planned = resolve_entry_info(
+            system, backend, plan,
+            workload=(int(seeds.shape[0]), max_branches))
+        mesh, axis = _flat_mesh(mesh)
+        ndev = mesh.devices.size
 
-    B = seeds.shape[0]
-    Bp = ((max(B, 1) + ndev - 1) // ndev) * ndev
-    padded = np.zeros((Bp,), np.uint32)
-    padded[:B] = seeds
-    keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(padded))     # (Bp, 2)
+        B = seeds.shape[0]
+        Bp = ((max(B, 1) + ndev - 1) // ndev) * ndev
+        padded = np.zeros((Bp,), np.uint32)
+        padded[:B] = seeds
+        keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(padded))     # (Bp, 2)
 
-    def attempt(be, plan):
-        comp = lower_with_backend(be, system, plan) if is_compiled(system) \
-            else compile_with_plan(be, system, plan)
-        c0s = jnp.broadcast_to(comp.init_config,
-                               (Bp,) + comp.init_config.shape)   # (Bp, m)
-        fn = _traces_shard_fn(mesh, axis, steps, max_branches, policy, be)
-        out = fn(comp, c0s, keys)
-        jax.block_until_ready(out.configs)
-        return out
+        def attempt(be, plan):
+            comp = lower_with_backend(be, system, plan) \
+                if is_compiled(system) \
+                else compile_with_plan(be, system, plan)
+            c0s = jnp.broadcast_to(comp.init_config,
+                                   (Bp,) + comp.init_config.shape)   # (Bp, m)
+            fn = _traces_shard_fn(mesh, axis, steps, max_branches, policy, be)
+            out = fn(comp, c0s, keys)
+            with TraceAnnotation("snp.traces.wait"):
+                jax.block_until_ready(out.configs)
+            return out
 
-    out = run_with_failover(attempt, be, plan, degradable=planned)
-    return TraceOut(*(x[:B] for x in out))
+        out = run_with_failover(attempt, be, plan, degradable=planned)
+        return TraceOut(*(x[:B] for x in out))
 
 
 @functools.lru_cache(maxsize=128)

@@ -48,6 +48,7 @@ from typing import List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .backend import (BackendLike, compile_with_plan, get_backend,
                       lower_with_backend, resolve_entry_info)
@@ -418,42 +419,61 @@ def explore(
     §Explore) and the sort keeps small/wave-dominated workloads.
     Archives are bit-identical between the two outside visited-overflow
     (see :func:`_explore_step`).
+
+    The call is one host span ``snp.explore`` on the profiler's clock
+    (``jax.profiler.TraceAnnotation``), with its phases nested inside:
+    ``snp.plan`` (backend and plan resolution), ``snp.lower`` (the
+    backend's compile or ``lower``), ``snp.explore.init`` (the initial
+    state's dispatch), ``snp.explore.wait`` (the device loop until done,
+    no transfer) and ``snp.explore.readback`` (the archive's transfer and
+    the result).  With no profiler session a span costs about a
+    microsecond; under ``jax.profiler.trace(dir)`` they land in the
+    trace beside the device's operations (TensorBoard or Perfetto).
     """
     dedup = resolve_dedup(dedup, frontier_cap=frontier_cap,
                           visited_cap=visited_cap, max_branches=max_branches)
-    # Branch work per step is bounded by frontier_cap × max_branches.
-    be, plan, planned = resolve_entry_info(
-        system, backend, plan, workload=(frontier_cap, max_branches))
-    if plan is not None and plan.num_shards > 1:
-        _resolve_comp(system, be, plan)   # caller error: raise, don't degrade
-    init_arr = None if init is None else jnp.asarray(init, jnp.int32)
+    with TraceAnnotation("snp.explore"):
+        # Branch work per step is bounded by frontier_cap × max_branches.
+        be, plan, planned = resolve_entry_info(
+            system, backend, plan, workload=(frontier_cap, max_branches))
+        if plan is not None and plan.num_shards > 1:
+            # caller error: raise, don't degrade
+            _resolve_comp(system, be, plan)
+        init_arr = None if init is None else jnp.asarray(init, jnp.int32)
 
-    def attempt(be, plan):
-        comp = _resolve_comp(system, be, plan)
-        state = _init_state(comp, frontier_cap, visited_cap, init_arr, dedup)
-        return _explore_chunked(
-            comp, be, state, max_steps=max_steps, max_branches=max_branches,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            fault_injector=fault_injector, dedup=dedup)
+        def attempt(be, plan):
+            comp = _resolve_comp(system, be, plan)
+            with TraceAnnotation("snp.explore.init"):
+                state = _init_state(comp, frontier_cap, visited_cap,
+                                    init_arr, dedup)
+            return _explore_chunked(
+                comp, be, state, max_steps=max_steps,
+                max_branches=max_branches, checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every,
+                fault_injector=fault_injector, dedup=dedup)
 
-    state = run_with_failover(attempt, be, plan, degradable=planned)
-    # single host sync: one explicit device_get of the final state (the
-    # explicit form keeps the whole call legal under a d2h transfer guard)
-    arch, n, fn, step, b_ovf, f_ovf, v_ovf = jax.device_get(
-        (state.archive, state.archive_n, state.frontier_n, state.step,
-         state.branch_overflow, state.frontier_overflow,
-         state.visited_overflow))
-    n = int(n)
-    ovf = (bool(b_ovf), bool(f_ovf), bool(v_ovf))
-    return ExploreResult(
-        configs=arch[:n],
-        num_discovered=n,
-        steps=int(step),
-        exhausted=int(fn) == 0 and not any(ovf),
-        branch_overflow=ovf[0],
-        frontier_overflow=ovf[1],
-        visited_overflow=ovf[2],
-    )
+        state = run_with_failover(attempt, be, plan, degradable=planned)
+        # the device loop's end, apart from the transfer that follows
+        with TraceAnnotation("snp.explore.wait"):
+            jax.block_until_ready(state)
+        # single host sync: one explicit device_get of the final state (the
+        # explicit form keeps the whole call legal under a d2h transfer guard)
+        with TraceAnnotation("snp.explore.readback"):
+            arch, n, fn, step, b_ovf, f_ovf, v_ovf = jax.device_get(
+                (state.archive, state.archive_n, state.frontier_n,
+                 state.step, state.branch_overflow, state.frontier_overflow,
+                 state.visited_overflow))
+            n = int(n)
+            ovf = (bool(b_ovf), bool(f_ovf), bool(v_ovf))
+            return ExploreResult(
+                configs=arch[:n],
+                num_discovered=n,
+                steps=int(step),
+                exhausted=int(fn) == 0 and not any(ovf),
+                branch_overflow=ovf[0],
+                frontier_overflow=ovf[1],
+                visited_overflow=ovf[2],
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -617,21 +637,27 @@ def run_traces(
     seeds = jnp.asarray(seeds, jnp.uint32)
     if seeds.ndim != 1:
         raise ValueError(f"seeds must be 1-D, got shape {seeds.shape}")
-    be, plan, planned = resolve_entry_info(
-        system, backend, plan, workload=(int(seeds.shape[0]), max_branches))
-    if plan is not None and plan.num_shards > 1:
-        _resolve_comp(system, be, plan)   # caller error: raise, don't degrade
-    keys = jax.vmap(jax.random.PRNGKey)(seeds)             # (B, 2)
+    with TraceAnnotation("snp.traces", batch=int(seeds.shape[0])):
+        be, plan, planned = resolve_entry_info(
+            system, backend, plan,
+            workload=(int(seeds.shape[0]), max_branches))
+        if plan is not None and plan.num_shards > 1:
+            # caller error: raise, don't degrade
+            _resolve_comp(system, be, plan)
+        keys = jax.vmap(jax.random.PRNGKey)(seeds)             # (B, 2)
 
-    def attempt(be, plan):
-        comp = _resolve_comp(system, be, plan)
-        c0s = jnp.broadcast_to(comp.init_config, (seeds.shape[0],) +
-                               comp.init_config.shape)
-        out = _traces_scan(comp, c0s, keys, steps, max_branches, policy, be)
-        jax.block_until_ready(out.configs)   # first-run failures degrade too
-        return out
+        def attempt(be, plan):
+            comp = _resolve_comp(system, be, plan)
+            c0s = jnp.broadcast_to(comp.init_config, (seeds.shape[0],) +
+                                   comp.init_config.shape)
+            out = _traces_scan(comp, c0s, keys, steps, max_branches, policy,
+                               be)
+            with TraceAnnotation("snp.traces.wait"):
+                # first-run failures degrade too
+                jax.block_until_ready(out.configs)
+            return out
 
-    return run_with_failover(attempt, be, plan, degradable=planned)
+        return run_with_failover(attempt, be, plan, degradable=planned)
 
 
 def run_trace(

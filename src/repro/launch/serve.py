@@ -105,14 +105,21 @@ def serve_snp(args) -> None:
                 failed += 1
                 print(f"[serve-snp] request failed: {type(e).__name__}: {e}")
         dt = time.perf_counter() - t0
-        calls = svc.num_device_calls
         stats = svc.stats()
     # outside the with-block: close() joined the drain thread, so every
     # done-callback has run (result() alone doesn't guarantee the last
     # future's callback fired before the waiter woke)
     lat_ms = np.asarray([done[s] - t0 for s in range(n)]) * 1e3
     print(f"[serve-snp] {n - failed}/{n} traces x {G} steps in "
-          f"{dt*1e3:.1f} ms ({n / dt:.0f} traces/s, {calls} device calls)")
+          f"{dt*1e3:.1f} ms ({n / dt:.0f} traces/s, "
+          f"{stats['device_calls']} device calls)")
+    if stats["queued_requests"] and stats["device_calls"]:
+        print(f"[serve-snp] mean queue wait "
+              f"{stats['queue_wait_us'] / stats['queued_requests'] / 1e3:.1f}"
+              f" ms, mean flush "
+              f"{stats['flush_us'] / stats['device_calls'] / 1e3:.1f} ms "
+              f"({stats['flush_device_us'] / stats['device_calls'] / 1e3:.1f}"
+              f" ms in the device call)")
     print(f"[serve-snp] completion latency p50={np.percentile(lat_ms, 50):.1f} ms "
           f"p99={np.percentile(lat_ms, 99):.1f} ms")
     if policy is not None or injector is not None:

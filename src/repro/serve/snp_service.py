@@ -30,6 +30,12 @@ sharding layout — live in DESIGN.md §4; the short version:
   default) the historical behavior is preserved exactly: one failure
   fails the whole co-batched flush.
 
+Observability: :meth:`~SNPTraceService.stats` counts calls, failures,
+queue wait and flush time, and each request and flush is a host span on
+the profiler's clock (``snp.serve.submit``, ``snp.serve.flush`` with
+``snp.serve.device``, ``snp.serve.readback`` and ``snp.serve.resolve``
+inside; DESIGN.md §4.5), recorded under ``jax.profiler.trace(dir)``.
+
 Per-trace PRNG keys mean padding/batching/flush-timing never changes a
 trajectory: the result for a request is bit-identical to a solo
 :func:`~repro.core.engine.run_trace` with the same seed, and async results
@@ -48,14 +54,16 @@ bench tier.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import failover
 from repro.core.backend import BackendLike, get_backend, lower_with_backend
@@ -120,7 +128,9 @@ class TraceResult:
 
 _STAT_KEYS = ("device_calls", "traces_served", "retries", "bisections",
               "degraded", "deadline_exceeded", "rejected", "failed_calls",
-              "failed_requests", "branch_overflow_traces")
+              "failed_requests", "branch_overflow_traces",
+              "queued_requests", "queue_wait_us", "flush_us",
+              "flush_device_us")
 
 
 class SNPTraceService:
@@ -180,6 +190,7 @@ class SNPTraceService:
         #: the last drain() definitively failed (replaced per drain)
         self.last_failures: Dict[int, BaseException] = {}
         self._tickets = itertools.count()
+        self._flush_ids = itertools.count()
         self._pending: Dict[int, TraceRequest] = {}
         self._comp_of: Dict[int, CompiledAny] = {}   # ticket -> compiled
         # compile memoization, keyed by SNPSystem (structural equality);
@@ -208,23 +219,27 @@ class SNPTraceService:
             self._stats[key] += n
 
     def stats(self) -> Dict[str, int]:
-        """Snapshot of the service counters: ``device_calls``,
-        ``traces_served``, and the failure-domain counters (``retries``,
-        ``bisections``, ``degraded``, ``deadline_exceeded``, ``rejected``,
-        ``failed_calls``, ``failed_requests``,
-        ``branch_overflow_traces``)."""
+        """Snapshot of the service counters, all ints:
+
+        * ``device_calls`` (runner calls that returned) and
+          ``traces_served``;
+        * the failure-domain counters ``retries``, ``bisections``,
+          ``degraded``, ``deadline_exceeded``, ``rejected``,
+          ``failed_calls``, ``failed_requests`` and
+          ``branch_overflow_traces``;
+        * the queue and flush clocks (``time.monotonic``, microseconds):
+          ``queued_requests`` (requests taken into a flush),
+          ``queue_wait_us`` (their summed wait from submit to the start of
+          their flush), ``flush_us`` (summed flush wall time, from the
+          start to the last result or future resolved) and
+          ``flush_device_us`` (summed time inside the runner, failed and
+          retried calls included).
+
+        A difference of two snapshots covers the work between them: e.g.
+        ``queue_wait_us / queued_requests`` is the mean queue wait and
+        ``flush_us / device_calls`` the mean flush without faults."""
         with self._cv:
             return dict(self._stats)
-
-    @property
-    def num_device_calls(self) -> int:
-        with self._cv:
-            return self._stats["device_calls"]
-
-    @property
-    def num_traces_served(self) -> int:
-        with self._cv:
-            return self._stats["traces_served"]
 
     # -- submission --------------------------------------------------------
 
@@ -288,15 +303,16 @@ class SNPTraceService:
                     f"{len(self._pending)} requests pending >= "
                     f"max_pending={pol.max_pending}")
             ticket = next(self._tickets)
-            self._pending[ticket] = request
-            self._comp_of[ticket] = comp
-            self._submit_t[ticket] = time.monotonic()
-            if not self.async_mode:
-                return ticket
-            fut: Future = Future()
-            self._futures[ticket] = fut
-            self._cv.notify_all()
-            return fut
+            with TraceAnnotation("snp.serve.submit", ticket=ticket):
+                self._pending[ticket] = request
+                self._comp_of[ticket] = comp
+                self._submit_t[ticket] = time.monotonic()
+                if not self.async_mode:
+                    return ticket
+                fut: Future = Future()
+                self._futures[ticket] = fut
+                self._cv.notify_all()
+                return fut
 
     @property
     def pending(self) -> int:
@@ -357,8 +373,9 @@ class SNPTraceService:
             born = dict(self._submit_t)
         if self.policy is None:
             for comp, policy, max_branches, chunk, reqs in batches:
-                results.update(self._run_batch(comp, policy, max_branches,
-                                               chunk, reqs))
+                with self._flush(chunk, reqs, born):
+                    results.update(self._run_batch(
+                        comp, policy, max_branches, chunk, reqs))
             # all-or-nothing: requests leave the pending maps only after
             # every batch served.  If any runner call raises, the whole
             # drain stays pending and a retry drain() re-serves it —
@@ -372,8 +389,9 @@ class SNPTraceService:
             return results
         failures: Dict[int, BaseException] = {}
         for comp, policy, max_branches, chunk, reqs in batches:
-            res, fail = self._serve_chunk(comp, policy, max_branches,
-                                          chunk, reqs, born)
+            with self._flush(chunk, reqs, born):
+                res, fail = self._serve_chunk(comp, policy, max_branches,
+                                              chunk, reqs, born)
             results.update(res)
             failures.update(fail)
         # under a policy every ticket was definitively resolved — served,
@@ -386,18 +404,46 @@ class SNPTraceService:
 
     # -- the device call ---------------------------------------------------
 
+    def _steps(self, reqs: List[TraceRequest]) -> int:
+        # submit() enforces steps <= max_steps, so no clamp is needed here
+        return _round_up(max(r.steps for r in reqs), self.step_bucket)
+
+    @contextlib.contextmanager
+    def _flush(self, tickets: List[int], reqs: List[TraceRequest],
+               born: Dict[int, float]) -> Iterator[None]:
+        """One chunk's flush: the ``snp.serve.flush`` span, and its share
+        of the queue and flush counters, whether or not it succeeds."""
+        start = time.monotonic()
+        with TraceAnnotation("snp.serve.flush", flush=next(self._flush_ids),
+                             first_ticket=tickets[0], n=len(tickets),
+                             steps=self._steps(reqs)):
+            try:
+                yield
+            finally:
+                wait = sum(start - born[t] for t in tickets)
+                with self._cv:
+                    self._stats["queued_requests"] += len(tickets)
+                    self._stats["queue_wait_us"] += round(wait * 1e6)
+                    self._stats["flush_us"] += round(
+                        (time.monotonic() - start) * 1e6)
+
     def _run_batch(self, comp: CompiledAny, policy: str, max_branches: int,
                    tickets: List[int], reqs: List[TraceRequest],
                    backend=None) -> Dict[int, TraceResult]:
-        # submit() enforces steps <= max_steps, so no clamp is needed here
         backend = self.backend if backend is None else backend
-        steps = _round_up(max(r.steps for r in reqs), self.step_bucket)
+        steps = self._steps(reqs)
         seeds = np.zeros((self.batch_size,), np.uint32)   # dummy pad: seed 0
         seeds[:len(reqs)] = [r.seed for r in reqs]
 
-        out = self.runner(
-            comp, steps=steps, seeds=seeds, policy=policy,
-            max_branches=max_branches, backend=backend)
+        start = time.monotonic()
+        try:
+            with TraceAnnotation("snp.serve.device"):
+                out = self.runner(
+                    comp, steps=steps, seeds=seeds, policy=policy,
+                    max_branches=max_branches, backend=backend)
+        finally:
+            self._count("flush_device_us",
+                        round((time.monotonic() - start) * 1e6))
         if len(out) == 4:
             cfgs, emis, alive, ovf = out
         else:   # third-party runner predating the branch_overflow field
@@ -406,16 +452,18 @@ class SNPTraceService:
         self._count("device_calls")
         self._count("traces_served", len(reqs))
 
-        cfgs, emis, alive, ovf = (np.asarray(cfgs), np.asarray(emis),
-                                  np.asarray(alive), np.asarray(ovf))
-        results = {
-            t: TraceResult(configs=cfgs[i, :r.steps],
-                           emissions=emis[i, :r.steps],
-                           alive=alive[i, :r.steps],
-                           branch_overflow=ovf[i, :r.steps])
-            for i, (t, r) in enumerate(zip(tickets, reqs))
-        }
-        truncated = sum(1 for r in results.values() if r.truncated)
+        with TraceAnnotation("snp.serve.readback"):
+            cfgs, emis, alive, ovf = (np.asarray(cfgs), np.asarray(emis),
+                                      np.asarray(alive), np.asarray(ovf))
+        with TraceAnnotation("snp.serve.resolve"):
+            results = {
+                t: TraceResult(configs=cfgs[i, :r.steps],
+                               emissions=emis[i, :r.steps],
+                               alive=alive[i, :r.steps],
+                               branch_overflow=ovf[i, :r.steps])
+                for i, (t, r) in enumerate(zip(tickets, reqs))
+            }
+            truncated = sum(1 for r in results.values() if r.truncated)
         if truncated:
             self._count("branch_overflow_traces", truncated)
         return results
@@ -599,37 +647,38 @@ class SNPTraceService:
                     continue
             for comp, policy, max_branches, tickets, reqs, futs, born \
                     in batches:
-                # claim RUNNING state first: a caller-cancelled future must
-                # be skipped, not written to (set_result on a cancelled
-                # Future raises and would kill this thread); once RUNNING,
-                # cancel() can no longer win the race.
-                live = [fut.set_running_or_notify_cancel() for fut in futs]
-                if self.policy is None:
-                    try:
-                        results = self._run_batch(
-                            comp, policy, max_branches, tickets, reqs)
-                    except BaseException as e:  # propagate into the futures
-                        for fut, ok in zip(futs, live):
-                            if ok:
-                                fut.set_exception(e)
-                        continue
-                    for t, fut, ok in zip(tickets, futs, live):
-                        if ok:
-                            fut.set_result(results[t])
-                    continue
-                try:
-                    results, failures = self._serve_chunk(
-                        comp, policy, max_branches, tickets, reqs, born)
-                except BaseException as e:  # recovery itself failed
-                    results, failures = {}, {t: e for t in tickets}
-                for t, fut, ok in zip(tickets, futs, live):
-                    if not ok:
-                        continue   # cancelled before the flush claimed it
-                    if t in results:
-                        fut.set_result(results[t])
-                    else:
-                        fut.set_exception(failures.get(t, RuntimeError(
-                            f"request {t} left unserved by recovery")))
+                with self._flush(tickets, reqs, born):
+                    self._flush_into(futs, comp, policy, max_branches,
+                                     tickets, reqs, born)
+
+    def _flush_into(self, futs: List[Future], comp: CompiledAny, policy: str,
+                    max_branches: int, tickets: List[int],
+                    reqs: List[TraceRequest], born: Dict[int, float]) -> None:
+        """Serve one taken chunk and resolve its futures."""
+        # claim RUNNING state first: a caller-cancelled future must be
+        # skipped, not written to (set_result on a cancelled Future raises
+        # and would kill the drain thread); once RUNNING, cancel() can no
+        # longer win the race.
+        live = [fut.set_running_or_notify_cancel() for fut in futs]
+        try:
+            if self.policy is None:
+                results = self._run_batch(
+                    comp, policy, max_branches, tickets, reqs)
+                failures: Dict[int, BaseException] = {}
+            else:
+                results, failures = self._serve_chunk(
+                    comp, policy, max_branches, tickets, reqs, born)
+        except BaseException as e:   # propagate into the futures
+            results, failures = {}, {t: e for t in tickets}
+        with TraceAnnotation("snp.serve.resolve"):
+            for t, fut, ok in zip(tickets, futs, live):
+                if not ok:
+                    continue   # cancelled before the flush claimed it
+                if t in results:
+                    fut.set_result(results[t])
+                else:
+                    fut.set_exception(failures.get(t, RuntimeError(
+                        f"request {t} left unserved by recovery")))
 
     # -- lifecycle ---------------------------------------------------------
 

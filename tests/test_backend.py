@@ -194,8 +194,8 @@ def test_service_batches_heterogeneous_requests():
     results = svc.drain()
     assert svc.pending == 0
     # three groups: (pi, random), (pi, first), (chain, random)
-    assert svc.num_device_calls == 3
-    assert svc.num_traces_served == 4
+    assert svc.stats()["device_calls"] == 3
+    assert svc.stats()["traces_served"] == 4
     for k, r in reqs.items():
         got = results[tickets[k]]
         c, e, a, *_ = run_trace(r.system, steps=r.steps, policy=r.policy,
@@ -212,7 +212,7 @@ def test_service_serves_256_trace_batch_in_one_call():
     tickets = [svc.submit(TraceRequest(pi, steps=8, policy="random", seed=s))
                for s in range(256)]
     results = svc.drain()
-    assert svc.num_device_calls == 1          # one jitted run_traces launch
+    assert svc.stats()["device_calls"] == 1   # one jitted run_traces launch
     assert len(results) == 256
     # spot-check a few against solo traces
     for s in (0, 17, 255):
@@ -229,7 +229,7 @@ def test_service_chunks_oversized_groups_and_pads_short_ones():
     tickets = [svc.submit(TraceRequest(pi, steps=3, seed=s, policy="random"))
                for s in range(6)]
     results = svc.drain()
-    assert svc.num_device_calls == 2          # 6 requests / batch_size 4
+    assert svc.stats()["device_calls"] == 2   # 6 requests / batch_size 4
     for s in range(6):
         c, _, _, *_ = run_trace(pi, steps=3, policy="random", seed=s)
         np.testing.assert_array_equal(results[tickets[s]].configs,

@@ -80,7 +80,7 @@ def test_full_group_flushes_without_deadline_or_close():
             got = fut.result(timeout=TIMEOUT)
             c, _, _, *_ = run_trace(PI, steps=3, policy="random", seed=s)
             np.testing.assert_array_equal(got.configs, np.asarray(c))
-        assert svc.num_device_calls == 1
+        assert svc.stats()["device_calls"] == 1
     finally:
         svc.close()
 
@@ -168,7 +168,7 @@ def test_flush_error_propagates_into_futures_and_thread_survives():
 def test_drain_with_zero_pending_returns_empty():
     svc = SNPTraceService(batch_size=4)
     assert svc.drain() == {}
-    assert svc.num_device_calls == 0
+    assert svc.stats()["device_calls"] == 0
 
 
 @pytest.mark.parametrize("failing_call", [1, 2])
@@ -206,7 +206,7 @@ def test_mixed_step_counts_share_one_group_and_one_call():
             for s in (1, 7, 13)]
     tickets = [svc.submit(r) for r in reqs]
     results = svc.drain()
-    assert svc.num_device_calls == 1   # one group, one padded batch
+    assert svc.stats()["device_calls"] == 1   # one group, one padded batch
     for t, r in zip(tickets, reqs):
         got = results[t]
         assert got.configs.shape[0] == r.steps   # sliced to the request
