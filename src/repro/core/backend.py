@@ -14,6 +14,9 @@ workload.  This module is that seam:
   ``lax.while_loop``, ``lax.scan`` and ``shard_map``), and all registered
   backends must agree bit-for-bit on the *valid* entries of
   :class:`~repro.core.semantics.StepOut` for spike counts < 2^24.
+  A backend may also offer ``step_chosen``, one successor per row at an
+  index the caller picks from the branch count; the trace scan uses it
+  where it exists and picks from ``expand`` otherwise.
 * :class:`RefBackend` (``"ref"``) — the pure-jnp oracle
   (:func:`~repro.core.semantics.next_configs`).
 * :class:`PallasBackend` (``"pallas"``) — the fused TPU kernel
@@ -73,7 +76,9 @@ from .matrix import (CompiledAny, CompiledSNP, CompiledSparseSNP,
 from .plan import (KernelConfig, ShardedCompiled, SystemPlan,
                    compile_sharded, is_sharded, lower_shard_dense,
                    resolve_interpret)
-from .semantics import (StepOut, delayed_next_configs, next_configs,
+from .semantics import (ChosenOut, StepOut, delayed_next_configs,
+                        next_configs, sparse_chosen_config,
+                        sparse_delayed_chosen_config,
                         sparse_delayed_next_configs, sparse_next_configs)
 from .system import SNPSystem
 
@@ -113,6 +118,18 @@ class StepBackend(Protocol):
       can round to it to avoid wasted lanes.
     * ``materializes_spiking`` — whether ``StepOut.spiking`` is populated
       (``None`` otherwise).
+
+    Optional method (not declared below, so ``isinstance`` does not demand
+    it): ``step_chosen(configs (B, m), comp, max_branches, choose) ->``
+    :class:`~repro.core.semantics.ChosenOut` — one successor per row
+    instead of all ``T``.  ``n_valid`` (B,) int32 is
+    ``sum(expand(...).valid, -1)``, ``choose(n_valid)`` returns one branch
+    index per row, and ``configs[b]``/``emissions[b]`` must be
+    bit-identical to ``expand``'s entries at that index (``overflow`` to
+    its ``overflow``).  The trace scan takes this path wherever the
+    backend has the method and picks from ``expand`` otherwise, so a
+    failover onto a backend without it changes no trace.  Exploration
+    always calls ``expand``: its frontier is the whole candidate set.
     """
 
     name: str
@@ -551,7 +568,8 @@ class SparseBackend:
     materializing the ``(B, T, n)`` one-hot spiking tensor or the dense
     ``(n, m)`` matrix.  Work and memory scale with ``nnz(M_Π)``
     (``O(B·T·m·degree)``) instead of ``O(B·T·n·m)``; valid entries are
-    bit-identical to ``"ref"`` for spike counts < 2^24.
+    bit-identical to ``"ref"`` for spike counts < 2^24.  ``step_chosen``
+    runs the same three stages on one branch per row, ``O(B·m·degree)``.
     """
 
     name: str = "sparse"
@@ -580,6 +598,16 @@ class SparseBackend:
         if is_delayed(comp):
             return sparse_delayed_next_configs(configs, comp, max_branches)
         return sparse_next_configs(configs, comp, max_branches)
+
+    def step_chosen(self, configs: jnp.ndarray, comp: CompiledSparseSNP,
+                    max_branches: int, choose) -> ChosenOut:
+        """The successor of each (B, m) row at ``choose(n_valid)``, built
+        alone (see the protocol's note on ``step_chosen``)."""
+        comp = _require_sparse(comp, self.name)
+        if is_delayed(comp):
+            return sparse_delayed_chosen_config(configs, comp, max_branches,
+                                                choose)
+        return sparse_chosen_config(configs, comp, max_branches, choose)
 
 
 @dataclass(frozen=True)

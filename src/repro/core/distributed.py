@@ -66,7 +66,8 @@ from jax import shard_map
 from .backend import (BackendLike, PallasBackend, SparsePallasBackend,
                       compile_with_plan, lower_with_backend, resolve_entry,
                       resolve_entry_info, supports_sharded)
-from .engine import ExploreResult, TraceOut, _traces_scan
+from .engine import (ExploreResult, TraceOut, _traces_scan,
+                     successors_per_step)
 from .failover import run_with_failover
 from .hashing import SENTINEL, config_hash, zobrist_hash
 from .hashtable import (HashTable, _base_slot, _canonical, first_occurrence,
@@ -839,7 +840,9 @@ def run_traces_distributed(
                                    (Bp,) + comp.init_config.shape)   # (Bp, m)
             fn = _traces_shard_fn(mesh, axis, steps, max_branches, policy, be)
             out = fn(comp, c0s, keys)
-            with TraceAnnotation("snp.traces.wait"):
+            with TraceAnnotation(
+                    "snp.traces.wait",
+                    successors=successors_per_step(be, max_branches)):
                 jax.block_until_ready(out.configs)
             return out
 

@@ -571,6 +571,13 @@ class TraceOut(NamedTuple):
     branch_overflow: jnp.ndarray  # (B, steps) bool
 
 
+def successors_per_step(backend, max_branches: int) -> int:
+    """Successors a trace step builds per trace: 1 where the backend has
+    ``step_chosen`` (choose, then step once), ``max_branches`` where the
+    scan expands every candidate and keeps one."""
+    return 1 if hasattr(backend, "step_chosen") else max_branches
+
+
 @functools.partial(
     jax.jit, static_argnames=("steps", "max_branches", "policy", "backend"))
 def _traces_scan(comp, c0s, keys, steps, max_branches, policy, backend):
@@ -579,28 +586,42 @@ def _traces_scan(comp, c0s, keys, steps, max_branches, policy, backend):
     ``c0s`` (B, m), ``keys`` (B, 2) — per-trace PRNG streams, split exactly
     as the single-trace path splits its key, so trace b depends only on
     ``keys[b]`` and batching never changes a trajectory.
+
+    The branch index depends only on the branch count and the key, so a
+    backend with ``step_chosen`` draws it first and builds one successor
+    per trace; any other backend expands all ``T`` candidates and keeps
+    the one at that index.  Both give the same trajectories.
     """
     B = c0s.shape[0]
 
     def body(carry, _):
         cfgs, keys = carry
-        out = backend.expand(cfgs, comp, max_branches)     # (B, T, m)
-        n_valid = jnp.sum(out.valid, axis=-1, dtype=jnp.int32)  # (B,)
         if policy == "random":
             pair = jax.vmap(jax.random.split)(keys)        # (B, 2, 2)
             keys, subs = pair[:, 0], pair[:, 1]
-            idx = jax.vmap(
-                lambda k, n: jax.random.randint(k, (), 0, jnp.maximum(n, 1))
-            )(subs, n_valid)
+
+            def choose(n_valid):
+                return jax.vmap(
+                    lambda k, n: jax.random.randint(
+                        k, (), 0, jnp.maximum(n, 1)))(subs, n_valid)
         else:
-            idx = jnp.zeros((B,), jnp.int32)
+            def choose(n_valid):
+                return jnp.zeros((B,), jnp.int32)
+        if hasattr(backend, "step_chosen"):
+            out = backend.step_chosen(cfgs, comp, max_branches, choose)
+            n_valid, pick, picked_emis = out.n_valid, out.configs, \
+                out.emissions
+        else:
+            out = backend.expand(cfgs, comp, max_branches)  # (B, T, m)
+            n_valid = jnp.sum(out.valid, axis=-1, dtype=jnp.int32)  # (B,)
+            idx = choose(n_valid)
+            pick = jnp.take_along_axis(
+                out.configs, idx[:, None, None], axis=1)[:, 0]  # (B, m)
+            picked_emis = jnp.take_along_axis(
+                out.emissions, idx[:, None], axis=1)[:, 0]
         has = n_valid > 0
-        pick = jnp.take_along_axis(
-            out.configs, idx[:, None, None], axis=1)[:, 0]  # (B, m)
         nxt = jnp.where(has[:, None], pick, cfgs)
-        emis = jnp.where(
-            has, jnp.take_along_axis(out.emissions, idx[:, None], axis=1)[:, 0],
-            0)
+        emis = jnp.where(has, picked_emis, 0)
         ovf = out.overflow & has
         return (nxt, keys), (nxt, emis, has, ovf)
 
@@ -623,9 +644,12 @@ def run_traces(
     Returns a :class:`TraceOut` — ``(configs (B, steps, m), emissions
     (B, steps), alive (B, steps), branch_overflow (B, steps))`` with
     ``B = len(seeds)``.  Row b is bit-identical to
-    ``run_trace(..., seed=seeds[b])`` with the same policy/backend — the
-    batch dimension rides through the backend's ``expand`` (one transition
-    per step for the whole batch), which is the serving-path hot loop.
+    ``run_trace(..., seed=seeds[b])`` with the same policy/backend — one
+    transition per step for the whole batch, which is the serving-path hot
+    loop.  Each step builds only the successor each trace keeps where the
+    backend has ``step_chosen`` (``"sparse"``), and all ``max_branches``
+    candidates through ``expand`` otherwise; the ``snp.traces.wait`` span
+    records which as ``successors`` per trace-step.
     ``backend=None`` (the default) hands the choice to the query planner
     under the default ``SystemPlan(mode="auto")`` — see :func:`explore`;
     traces are backend-independent, so the planner only moves wall-time,
@@ -652,7 +676,9 @@ def run_traces(
                                    comp.init_config.shape)
             out = _traces_scan(comp, c0s, keys, steps, max_branches, policy,
                                be)
-            with TraceAnnotation("snp.traces.wait"):
+            with TraceAnnotation(
+                    "snp.traces.wait",
+                    successors=successors_per_step(be, max_branches)):
                 # first-run failures degrade too
                 jax.block_until_ready(out.configs)
             return out

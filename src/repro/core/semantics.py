@@ -15,7 +15,10 @@ explicit VMEM tiling; this module doubles as its oracle (``ref.py``).
 The sparse twins (:func:`sparse_branch_info`, :func:`sparse_next_configs`)
 run the same math on the ELL/segment encoding
 (:class:`~repro.core.matrix.CompiledSparseSNP`) in ``O(B·T·nnz)`` with
-bit-identical valid entries — see DESIGN.md §3.
+bit-identical valid entries — see DESIGN.md §3.  The chosen-successor
+twins (:func:`sparse_chosen_config`, :func:`sparse_delayed_chosen_config`)
+count the branches first, let the caller pick one per row, and build only
+that successor: the trace scan keeps one of the ``T`` anyway.
 
 Enumeration order.  Neuron 0 is the most-significant mixed-radix digit:
 branch index ``t ∈ [0, Ψ)`` decodes to ``digit_i = (t // stride_i) % k_i``
@@ -49,8 +52,10 @@ __all__ = [
     "spiking_vectors",
     "next_configs",
     "sparse_next_configs",
+    "sparse_chosen_config",
     "in_adjacency_sum",
     "StepOut",
+    "ChosenOut",
     "split_state",
     "delayed_branch_info",
     "sparse_delayed_branch_info",
@@ -58,6 +63,7 @@ __all__ = [
     "delayed_packed_actions",
     "delayed_next_configs",
     "sparse_delayed_next_configs",
+    "sparse_delayed_chosen_config",
 ]
 
 # Every f32 contraction on the SNP path runs at full f32 precision, so the
@@ -292,7 +298,9 @@ def packed_rule_table(info: BranchInfo, comp: CompiledSparseSNP,
 
 def _decode_digits(t: jnp.ndarray, info: BranchInfo) -> jnp.ndarray:
     """Mixed-radix digit per (branch, neuron): ``(t // stride) % choices``
-    as (..., T, m) int32, computed in float32.
+    as (B, T', m) int32, computed in float32.  ``t`` is ``(T',)`` branch
+    indices shared by every row of the (B, ...) ``info``, or ``(B, T')``
+    one set per row.
 
     Integer division does not vectorize on CPU (and costs ~20x a float op);
     f32 division is *exact* here: with ``j = floor(t/stride)``, a wrong
@@ -301,7 +309,7 @@ def _decode_digits(t: jnp.ndarray, info: BranchInfo) -> jnp.ndarray:
     for ``T < 2^23``.  Saturated (+inf) strides quotient to 0, matching the
     dense path's clamped-int division.  Same argument for the modulus.
     """
-    tf = t.astype(jnp.float32).reshape((1,) * (info.stride.ndim - 1) + (-1, 1))
+    tf = t.astype(jnp.float32)[..., None]
     s = info.stride[..., None, :]
     c = info.choices.astype(jnp.float32)[..., None, :]
     q = jnp.floor(tf / s)
@@ -363,6 +371,74 @@ def in_adjacency_sum(vals: jnp.ndarray, in_idx: jnp.ndarray,
     return acc.T.reshape(*lead, m)
 
 
+class ChosenOut(NamedTuple):
+    """One chosen successor per row (:func:`sparse_chosen_config`)."""
+
+    configs: jnp.ndarray    # (B, w) int32 — the successor at the chosen branch
+    emissions: jnp.ndarray  # (B,) int32
+    n_valid: jnp.ndarray    # (B,) int32 — number of valid branches (<= T)
+    overflow: jnp.ndarray   # (B,) bool — Ψ exceeded max_branches
+
+
+def _branch_valid(info: BranchInfo, T: int) -> jnp.ndarray:
+    """(B, T) bool: branch ``t`` exists (``t < Ψ`` in float32, so a
+    saturated Ψ keeps every branch) at a live config."""
+    t = jnp.arange(T, dtype=jnp.int32)
+    return (t[None, :].astype(jnp.float32) < info.psi[:, None]) \
+        & info.alive[:, None]
+
+
+def _sparse_successors(cfg: jnp.ndarray, info: BranchInfo,
+                       comp: CompiledSparseSNP, t: jnp.ndarray
+                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Successors of the (B, m) rows ``cfg`` at branch indices ``t`` —
+    ``(T',)`` shared or ``(B, T')`` per row — as ``(configs (B, T', m),
+    emissions (B, T'))``.  Steps 1–4 of :func:`sparse_next_configs`."""
+    tab = packed_rule_table(info, comp)                      # (B, m, R)
+    digits = _decode_digits(t, info)                         # (B, T', m)
+    packed_f = _fired_packed(digits, tab)                    # (B, T', m)
+    prod_f = packed_f & 0xFFFF
+    cons_f = packed_f >> 16
+
+    prod_pad = jnp.concatenate(
+        [prod_f, jnp.zeros(prod_f.shape[:-1] + (1,), jnp.int32)],
+        axis=-1)                                             # (B, T', m+1)
+    delta = in_adjacency_sum(prod_pad, comp.in_idx, comp.coo_src,
+                             comp.coo_dst) - cons_f
+    return (cfg[:, None, :] + delta,
+            jnp.take(prod_pad, comp.out_neuron, axis=-1))
+
+
+def _expand(config: jnp.ndarray, comp: CompiledSparseSNP, T: int,
+            info_fn, successors) -> StepOut:
+    """All ``T`` candidate successors of every row (the explore path)."""
+    w = config.shape[-1]
+    batch = config.shape[:-1]
+    cfg = config.reshape(-1, w)
+    info = info_fn(cfg, comp)
+    out, emissions = successors(cfg, info, comp,
+                                jnp.arange(T, dtype=jnp.int32))
+    return StepOut(
+        configs=out.reshape(*batch, T, w),
+        valid=_branch_valid(info, T).reshape(*batch, T),
+        emissions=emissions.reshape(*batch, T),
+        overflow=(info.psi > float(T)).reshape(batch),
+        spiking=None,
+    )
+
+
+def _choose_then_step(config: jnp.ndarray, comp: CompiledSparseSNP, T: int,
+                      choose, info_fn, successors) -> ChosenOut:
+    """Count each row's valid branches, let ``choose`` pick one index per
+    row, and build only that successor (the trace path)."""
+    info = info_fn(config, comp)
+    n_valid = jnp.sum(_branch_valid(info, T), axis=-1, dtype=jnp.int32)
+    idx = choose(n_valid)                                    # (B,)
+    out, emissions = successors(config, info, comp, idx[:, None])
+    return ChosenOut(configs=out[:, 0], emissions=emissions[:, 0],
+                     n_valid=n_valid, overflow=info.psi > float(T))
+
+
 def sparse_next_configs(
     config: jnp.ndarray, comp: CompiledSparseSNP, max_branches: int
 ) -> StepOut:
@@ -385,38 +461,23 @@ def sparse_next_configs(
     All arithmetic is int32 (exact); agreement with the dense f32 matmul
     holds for spike counts < 2^24 (DESIGN.md §2).
     """
-    m = config.shape[-1]
-    batch = config.shape[:-1]
-    cfg = config.reshape(-1, m)
-    B = cfg.shape[0]
-    T = max_branches
+    return _expand(config, comp, max_branches, sparse_branch_info,
+                   _sparse_successors)
 
-    info = sparse_branch_info(cfg, comp)
-    tab = packed_rule_table(info, comp)                      # (B, m, R)
 
-    t = jnp.arange(T, dtype=jnp.int32)
-    digits = _decode_digits(t, info)                         # (B, T, m)
-    packed_f = _fired_packed(digits, tab)                    # (B, T, m)
-    prod_f = packed_f & 0xFFFF
-    cons_f = packed_f >> 16
+def sparse_chosen_config(config: jnp.ndarray, comp: CompiledSparseSNP,
+                         max_branches: int, choose) -> ChosenOut:
+    """One step of each (B, m) row down a single branch: the successor
+    :func:`sparse_next_configs` would put at ``choose(n_valid)``, built
+    alone.
 
-    prod_pad = jnp.concatenate(
-        [prod_f, jnp.zeros((B, T, 1), jnp.int32)], axis=-1)  # (B, T, m+1)
-    delta = in_adjacency_sum(prod_pad, comp.in_idx, comp.coo_src,
-                             comp.coo_dst) - cons_f
-
-    out = cfg[:, None, :] + delta
-    valid = (t[None, :].astype(jnp.float32) < info.psi[:, None]) \
-        & info.alive[:, None]
-    overflow = info.psi > float(T)
-    emissions = jnp.take(prod_pad, comp.out_neuron, axis=-1)
-    return StepOut(
-        configs=out.reshape(*batch, T, m),
-        valid=valid.reshape(*batch, T),
-        emissions=emissions.reshape(*batch, T),
-        overflow=overflow.reshape(batch),
-        spiking=None,
-    )
+    ``n_valid`` (B,) int32 is ``sum(valid)`` of the full expansion, and
+    ``choose`` maps it to one branch index per row (traceable, < T).  The
+    decode, fired-rule lookup and in-adjacency contraction then run on
+    ``B`` rows instead of ``B·T``, with the same int32 arithmetic, so the
+    result is bit-identical to picking that row of the expansion."""
+    return _choose_then_step(config, comp, max_branches, choose,
+                             sparse_branch_info, _sparse_successors)
 
 
 # ---------------------------------------------------------------------------
@@ -558,6 +619,45 @@ def delayed_next_configs(
                    overflow=overflow, spiking=S)
 
 
+def _sparse_delayed_successors(cfg: jnp.ndarray, info: BranchInfo,
+                               comp: CompiledSparseSNP, t: jnp.ndarray
+                               ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`_sparse_successors` under the delayed semantics: the (B, 3m)
+    state rows at branch indices ``t``, as ``(configs (B, T', 3m),
+    emissions (B, T'))``."""
+    spikes, cd, pd = split_state(cfg)
+    packed_e, packed_d = delayed_packed_actions(comp)
+    etab = packed_rule_table(info, comp, packed_e)           # (B, m, R)
+    dtab = packed_rule_table(info, comp, packed_d)
+
+    digits = _decode_digits(t, info)                         # (B, T', m)
+    pe = _fired_packed(digits, etab)
+    prod_now = pe & 0xFFFF
+    cons_f = pe >> 16
+    pdl = _fired_packed(digits, dtab)
+    fired_del = pdl != 0
+    prod_pend = pdl & 0xFFFF
+    d_f = pdl >> 16
+
+    reopen = (cd == 1)[:, None, :]
+    emit = prod_now + jnp.where(reopen, pd[:, None, :], 0)   # (B, T', m)
+    emit_pad = jnp.concatenate(
+        [emit, jnp.zeros(emit.shape[:-1] + (1,), jnp.int32)], axis=-1)
+    incoming = in_adjacency_sum(emit_pad, comp.in_idx, comp.coo_src,
+                                comp.coo_dst)
+
+    cd_next = jnp.where(fired_del, d_f,
+                        jnp.maximum(cd - 1, 0)[:, None, :])
+    gate = cd_next == 0
+    spikes_next = spikes[:, None, :] - cons_f \
+        + jnp.where(gate, incoming, 0)
+    pd_next = jnp.where(fired_del, prod_pend,
+                        jnp.where(reopen, 0, pd[:, None, :]))
+
+    out = jnp.concatenate([spikes_next, cd_next, pd_next], axis=-1)
+    return out, jnp.take(emit_pad, comp.out_neuron, axis=-1)
+
+
 def sparse_delayed_next_configs(
     config: jnp.ndarray, comp: CompiledSparseSNP, max_branches: int
 ) -> StepOut:
@@ -571,53 +671,16 @@ def sparse_delayed_next_configs(
     action (``produce | d << 16``) to drive countdown/pending updates and
     the receiver gate.
     """
-    width = config.shape[-1]
-    batch = config.shape[:-1]
-    cfg = config.reshape(-1, width)
-    spikes, cd, pd = split_state(cfg)
-    m = spikes.shape[-1]
-    B = cfg.shape[0]
-    T = max_branches
+    return _expand(config, comp, max_branches, sparse_delayed_branch_info,
+                   _sparse_delayed_successors)
 
-    info = sparse_delayed_branch_info(cfg, comp)
-    packed_e, packed_d = delayed_packed_actions(comp)
-    etab = packed_rule_table(info, comp, packed_e)           # (B, m, R)
-    dtab = packed_rule_table(info, comp, packed_d)
 
-    t = jnp.arange(T, dtype=jnp.int32)
-    digits = _decode_digits(t, info)                         # (B, T, m)
-    pe = _fired_packed(digits, etab)
-    prod_now = pe & 0xFFFF
-    cons_f = pe >> 16
-    pdl = _fired_packed(digits, dtab)
-    fired_del = pdl != 0
-    prod_pend = pdl & 0xFFFF
-    d_f = pdl >> 16
-
-    reopen = (cd == 1)[:, None, :]
-    emit = prod_now + jnp.where(reopen, pd[:, None, :], 0)   # (B, T, m)
-    emit_pad = jnp.concatenate(
-        [emit, jnp.zeros((B, T, 1), jnp.int32)], axis=-1)
-    incoming = in_adjacency_sum(emit_pad, comp.in_idx, comp.coo_src,
-                                comp.coo_dst)
-
-    cd_next = jnp.where(fired_del, d_f,
-                        jnp.maximum(cd - 1, 0)[:, None, :])
-    gate = cd_next == 0
-    spikes_next = spikes[:, None, :] - cons_f \
-        + jnp.where(gate, incoming, 0)
-    pd_next = jnp.where(fired_del, prod_pend,
-                        jnp.where(reopen, 0, pd[:, None, :]))
-
-    out = jnp.concatenate([spikes_next, cd_next, pd_next], axis=-1)
-    valid = (t[None, :].astype(jnp.float32) < info.psi[:, None]) \
-        & info.alive[:, None]
-    overflow = info.psi > float(T)
-    emissions = jnp.take(emit_pad, comp.out_neuron, axis=-1)
-    return StepOut(
-        configs=out.reshape(*batch, T, width),
-        valid=valid.reshape(*batch, T),
-        emissions=emissions.reshape(*batch, T),
-        overflow=overflow.reshape(batch),
-        spiking=None,
-    )
+def sparse_delayed_chosen_config(config: jnp.ndarray,
+                                 comp: CompiledSparseSNP,
+                                 max_branches: int, choose) -> ChosenOut:
+    """:func:`sparse_chosen_config` under the delayed semantics: the
+    (B, 3m) successor :func:`sparse_delayed_next_configs` would put at
+    ``choose(n_valid)``, built alone."""
+    return _choose_then_step(config, comp, max_branches, choose,
+                             sparse_delayed_branch_info,
+                             _sparse_delayed_successors)

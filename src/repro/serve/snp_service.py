@@ -1,8 +1,10 @@
 """Batched SNP trace serving: heterogeneous requests -> padded device batches.
 
 The engine's :func:`~repro.core.engine.run_traces` is the device-side hot
-loop (one ``lax.scan``, whole batch through one ``StepBackend.expand`` per
-step); this module is the host-side front end that makes it a service.
+loop (one ``lax.scan``, whole batch through one transition per step, which
+builds only the successor each trace keeps where the backend has
+``step_chosen``); this module is the host-side front end that makes it a
+service.
 Architecture notes — batching/bucketing rules, the group key, the async
 drain state machine, the failure-domain state machine, and the mesh
 sharding layout — live in DESIGN.md §4; the short version:

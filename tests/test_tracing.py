@@ -81,6 +81,19 @@ def test_traces_call_spans_nest_in_order(entry, tmp_path):
         assert _only(_inside(spans, call), TRACES_PHASES) == TRACES_PHASES
 
 
+@pytest.mark.parametrize("backend,successors", [("sparse", 1), ("ref", 8)])
+def test_traces_wait_span_counts_successors_per_step(backend, successors,
+                                                     tmp_path):
+    """``snp.traces.wait`` says how many successors each trace-step built:
+    one where the backend chooses first (``step_chosen``), all ``T`` where
+    the scan expands and picks."""
+    with jax.profiler.trace(str(tmp_path)):
+        run_traces(PI, steps=4, seeds=np.arange(3), policy="random",
+                   max_branches=8, backend=backend)
+    waits = [s[3] for s in _spans(tmp_path) if s[0] == "snp.traces.wait"]
+    assert waits == [{"successors": successors}]
+
+
 def _sleeping_runner(comp, *, steps, seeds, **_):
     """A runner that takes ``SLEEP_S`` and returns all-zero traces."""
     time.sleep(SLEEP_S)
