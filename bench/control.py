@@ -28,15 +28,17 @@ if __package__ in (None, ""):
 
 @contextlib.contextmanager
 def planted(state: str = "bfloat16"):
-    """Within the block, ``engine.run_traces``, ``engine.explore`` and the
-    service's runner compute with the plain reference at ``state``
-    precision, on the system the harness last built."""
+    """Within the block, every program entry point a cell can drive
+    (``engine.run_traces``, ``engine.explore``, the service's runner and
+    ``distributed.explore_distributed``) computes with the plain
+    reference at ``state`` precision, on the system the harness last
+    built."""
     import jax.numpy as jnp
     import numpy as np
 
     from bench import systems
     from bench.reference import Reference
-    from repro.core import engine
+    from repro.core import distributed, engine
     from repro.serve import snp_service
 
     built = {}
@@ -48,10 +50,11 @@ def planted(state: str = "bfloat16"):
         return plain
 
     def run_traces(system, *, steps, seeds, policy="first", max_branches=64,
-                   backend=None, plan=None):
+                   backend=None, plan=None, init=None):
         if policy != "random":
             raise ValueError(f"the control draws random traces, not {policy}")
-        out = built["ref"].traces(np.asarray(seeds), steps, max_branches)
+        out = built["ref"].traces(np.asarray(seeds), steps, max_branches,
+                                  init=init)
         return engine.TraceOut(
             jnp.asarray(out.configs, jnp.int32),
             jnp.asarray(out.emissions, jnp.int32),
@@ -72,10 +75,20 @@ def planted(state: str = "bfloat16"):
             branch_overflow=overflow[0], frontier_overflow=overflow[1],
             visited_overflow=overflow[2])
 
+    def explore_sharded(system, *, plan=None, visited_cap=2048, **kw):
+        # the neuron-sharded scheme's frontier is global and its visited
+        # capacity per shard; the dense-row scheme has no stand-in here
+        if plan is None or plan.num_shards < 2:
+            raise ValueError("the control stands in for the neuron-sharded "
+                             "explore_distributed only")
+        return explore(system, visited_cap=visited_cap * plan.num_shards,
+                       **kw)
+
     patches = [(systems, "build", build_and_keep),
                (engine, "run_traces", run_traces),
                (engine, "explore", explore),
-               (snp_service, "run_traces", run_traces)]
+               (snp_service, "run_traces", run_traces),
+               (distributed, "explore_distributed", explore_sharded)]
     saved = [(mod, name, getattr(mod, name)) for mod, name, _ in patches]
     try:
         for mod, name, fn in patches:

@@ -11,7 +11,7 @@ ROOT = Path(__file__).resolve().parents[2]
 
 
 def read(r):
-    if r.entry != "explore":
+    if r.entry not in ("explore", "explore_distributed"):
         return None
     spans = program_spans.window_spans(ROOT, r.trace["window_s"]) or []
     waits = [s for s in spans if s.name == "snp.explore.wait"]

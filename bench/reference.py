@@ -196,15 +196,21 @@ class Reference:
 
     # -- traces --------------------------------------------------------------
 
-    def traces(self, seeds, steps: int, max_branches: int) -> Traces:
-        """Random-policy trajectories of the given per-trace seeds."""
+    def traces(self, seeds, steps: int, max_branches: int,
+               init: Optional[np.ndarray] = None) -> Traces:
+        """Random-policy trajectories of the given per-trace seeds, from
+        ``init``: one ``(m,)`` configuration for every trace or one row
+        per trace ``(k, m)`` (default: the system's initial
+        configuration)."""
         import jax
         import jax.numpy as jnp
         pick = _pick_fn()
         k = len(seeds)
         keys = jax.vmap(jax.random.PRNGKey)(
             jnp.asarray(np.asarray(seeds, np.uint32)))
-        C = np.broadcast_to(self.sys.init, (k, self.sys.num_neurons)).copy()
+        C = np.broadcast_to(self.sys.init if init is None else
+                            np.asarray(init, np.int64),
+                            (k, self.sys.num_neurons)).copy()
         cfgs, emis, alive, ovf = [], [], [], []
         for _ in range(steps):
             app, rank, choices, live = self._info(C)

@@ -1,12 +1,11 @@
 """Every cell of ``BENCHMARK.json`` runs end to end on the CPU at a tiny size
 and comes out correct; with the timed path broken underneath, the same
-run comes out not correct."""
+run comes out not correct.  A cell on several chips runs on as many
+virtual devices, in a subprocess."""
 
-import dataclasses
 import sys
 from pathlib import Path
 
-import numpy as np
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
@@ -14,9 +13,9 @@ import benchkit  # noqa: E402
 
 from bench import spec  # noqa: E402
 
-CELLS = [c["name"] for c in spec.load_spec(benchkit.ROOT)["workloads"]]
-ENTRY_OF = {c["name"]: spec.load_mix(benchkit.ROOT, c["traffic"])["entry"]
-            for c in spec.load_spec(benchkit.ROOT)["workloads"]}
+SPEC = spec.load_spec(benchkit.ROOT)
+CELLS = [c["name"] for c in SPEC["workloads"]]
+MULTI_CHIP = [c["name"] for c in SPEC["workloads"] if c["chips"] > 1]
 
 
 @pytest.fixture(scope="module")
@@ -25,8 +24,8 @@ def root(tmp_path_factory):
 
 
 @pytest.mark.parametrize("workload", CELLS)
-def test_cell_runs_and_is_correct(root, workload, monkeypatch):
-    res = benchkit.run_tiny(root, workload, monkeypatch)
+def test_cell_runs_and_is_correct(root, workload):
+    res = benchkit.run_tiny(root, workload)
     assert res["correct"] is True, res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
     cells = spec.load_spec(root)
@@ -37,80 +36,23 @@ def test_cell_runs_and_is_correct(root, workload, monkeypatch):
     assert list(res)[-1] == "checks"
     assert all(c["value"] == 0 and c["limit"] == 0
                for c in res["checks"].values())
+    assert res["device"]["count"] >= cell["chips"]
 
 
 # -- faults of the timed path ------------------------------------------------
 
 
-def _break_traces(monkeypatch, fault):
-    import jax.numpy as jnp
-
-    from repro.core import engine
-    from repro.serve import snp_service
-    orig = engine.run_traces
-
-    def run(comp, *, steps, seeds, **kw):
-        if fault == "half_batch":
-            seeds = np.asarray(seeds)
-            half = orig(comp, steps=steps, seeds=seeds[:len(seeds) // 2],
-                        **kw)
-            return type(half)(*(jnp.concatenate(
-                [a, jnp.zeros((len(seeds) - a.shape[0],) + a.shape[1:],
-                              a.dtype)]) for a in half))
-        out = orig(comp, steps=steps, seeds=seeds, **kw)
-        if fault == "unchanged":
-            return out._replace(configs=jnp.broadcast_to(
-                comp.init_config, out.configs.shape))
-        return out._replace(configs=out.configs.at[:, -1, 0].add(1))
-
-    monkeypatch.setattr(engine, "run_traces", run)
-    monkeypatch.setattr(snp_service, "run_traces", run)
-    if fault == "half_batch":
-        # the service pads its batch: leave out half of its requests
-        run_batch = snp_service.SNPTraceService._run_batch
-
-        def half_requests(self, comp, policy, max_branches, tickets, reqs,
-                          backend=None):
-            out = run_batch(self, comp, policy, max_branches, tickets, reqs,
-                            backend)
-            for t in tickets[(len(tickets) + 1) // 2:]:
-                r = out[t]
-                out[t] = dataclasses.replace(
-                    r, configs=np.zeros_like(r.configs))
-            return out
-
-        monkeypatch.setattr(snp_service, "run_traces", orig)
-        monkeypatch.setattr(snp_service.SNPTraceService, "_run_batch",
-                            half_requests)
-
-
-def _break_explore(monkeypatch, fault):
-    from repro.core import engine
-    orig = engine.explore
-
-    def run(comp, **kw):
-        if fault == "half_batch":
-            kw["frontier_cap"] //= 2
-            return orig(comp, **kw)
-        res = orig(comp, **kw)
-        if fault == "unchanged":
-            return dataclasses.replace(res, configs=res.configs[:1],
-                                       num_discovered=1)
-        configs = res.configs.copy()
-        configs[-1, 0] += 1
-        return dataclasses.replace(res, configs=configs)
-
-    monkeypatch.setattr(engine, "explore", run)
-
-
 @pytest.mark.parametrize("fault", ["unchanged", "half_batch", "altered"])
 @pytest.mark.parametrize("workload", CELLS)
-def test_broken_timed_path_is_not_correct(root, workload, fault,
-                                          monkeypatch):
-    if ENTRY_OF[workload] == "explore":
-        _break_explore(monkeypatch, fault)
-    else:
-        _break_traces(monkeypatch, fault)
-    res = benchkit.run_tiny(root, workload, monkeypatch)
+def test_broken_timed_path_is_not_correct(root, workload, fault):
+    res = benchkit.run_tiny(root, workload, plant=f"fault:{fault}")
+    assert res["correct"] is False, res["checks"]
+    assert any(c["value"] > c["limit"] for c in res["checks"].values())
+
+
+@pytest.mark.parametrize("workload", MULTI_CHIP)
+def test_exchange_left_out_is_not_correct(root, workload):
+    """The halo ``all_to_all`` between chips delivers nothing."""
+    res = benchkit.run_tiny(root, workload, plant="fault:no_exchange")
     assert res["correct"] is False, res["checks"]
     assert any(c["value"] > c["limit"] for c in res["checks"].values())

@@ -1,6 +1,7 @@
 """The plain reference agrees with the program at small sizes, and its
 lower-precision control (bfloat16 state) fails the same comparison."""
 
+import dataclasses
 import sys
 from pathlib import Path
 
@@ -56,6 +57,29 @@ def test_explore_from_another_init_matches_the_program(monkeypatch,
     np.testing.assert_array_equal(ref.configs[0], init)
     assert (res.steps, res.branch_overflow, res.frontier_overflow,
             res.visited_overflow) == ref[1:]
+
+
+def test_traces_from_one_init_per_trace():
+    """``init`` of shape ``(k, m)`` gives each trace its own start: row
+    ``r`` equals the trace of a system whose initial spikes are that
+    row's; ``(m,)`` starts them all there; ``None`` is the system's."""
+    plain = power_law(benchkit.BIG_SEED, 300, 4, max_in=16)
+    seeds = SEEDS[:5]
+    rows = np.stack([np.random.default_rng(i).permutation(plain.init)
+                     for i in range(len(seeds))])
+    got = reference.Reference(plain).traces(seeds, 16, 32, init=rows)
+    for r, row in enumerate(rows):
+        own = reference.Reference(dataclasses.replace(plain, init=row))
+        want = own.traces(seeds[r:r + 1], 16, 32)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g[r], w[0])
+    one = reference.Reference(plain).traces(seeds, 16, 32, init=rows[1])
+    np.testing.assert_array_equal(
+        one.configs[1], got.configs[1])
+    default = reference.Reference(plain).traces(seeds, 16, 32)
+    same = reference.Reference(plain).traces(seeds, 16, 32, init=plain.init)
+    for d, s in zip(default, same):
+        np.testing.assert_array_equal(d, s)
 
 
 def test_control_fails_traces():

@@ -58,6 +58,7 @@ def test_cell_finds_its_files_and_metrics(cell):
     if mix["loop"] == "open":
         assert callable(spec.plugin(benchkit.ROOT, "arrivals",
                                     mix["arrival"]).offsets)
+    assert mix.get("shards", 1) == cell["chips"]
     e2e = {m["name"] for m in spec.end_to_end_for(SPEC, cell)}
     assert "setup_s" in e2e and len(e2e) >= 2
     layer = spec.per_layer_for(SPEC, cell)
@@ -137,44 +138,66 @@ class Entry(entrykit.Entry):
 '''
 
 
-def test_new_config_mix_and_metric_are_new_files_only(tmp_path,
-                                                      monkeypatch):
+def _add_config(src, name, generator, args, tiny_args=None):
+    (src / "bench" / "configs" / f"{name}.json").write_text(json.dumps({
+        "name": name, "generator": generator, "args": args, "reduced": []}))
+    if tiny_args is not None:
+        benchkit.tiny_file(src, "configs", name).write_text(
+            json.dumps(tiny_args))
+    return {"name": name, "source": "test",
+            "file": f"bench/configs/{name}.json", "reduced": [],
+            "why": "test"}
+
+
+def _add_mix(src, name, mix, tiny_mix=None):
+    (src / "bench" / "traffic" / f"{name}.json").write_text(json.dumps(mix))
+    if tiny_mix is not None:
+        benchkit.tiny_file(src, "traffic", name).write_text(
+            json.dumps(tiny_mix))
+
+
+def _cell(name, config, traffic):
+    return {"name": name, "config": config, "traffic": traffic, "chips": 1,
+            "why": "test"}
+
+
+def _snapshot(root):
+    return {p.relative_to(root): p.read_bytes()
+            for sub in ("bench", benchkit.TINY) for p in (root / sub).rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_new_config_mix_and_metric_are_new_files_only(tmp_path):
     """A new configuration, mix and metric, and a new kind of cell (its own
     generator, entry point and arrival process), are new files and new
-    ``BENCHMARK.json`` entries: no file under ``bench/`` changes."""
-    root = benchkit.tiny_root(tmp_path / "checkout")
-    bench = root / "bench"
-    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
-    (bench / "configs" / "powerlaw-300.json").write_text(json.dumps({
-        "name": "powerlaw-300", "generator": "power_law",
-        "args": {"m": 300, "attach": 3}, "reduced": []}))
-    (bench / "traffic" / "short_traces.json").write_text(
-        json.dumps({"entry": "run_traces", "loop": "closed", "batch": 8,
-                    "steps": 5, "max_branches": 8, "policy": "random",
-                    "check_rows_per_call": 8}))
+    ``BENCHMARK.json`` entries: no file under ``bench/`` or its tiny sizes
+    changes.  Entered in ``BENCHMARK.json`` before the tiny root is made,
+    with their tiny files, they run at their tiny sizes, and every
+    existing cell reads the same tiny files as without them."""
+    src = benchkit.copy_checkout(tmp_path / "src")
+    bench = src / "bench"
+    before = _snapshot(src)
+    cells = json.loads((src / "BENCHMARK.json").read_text())
+    cells["configs"] += [
+        _add_config(src, "powerlaw-300", "power_law",
+                    {"m": 3000, "attach": 3}, {"m": 300}),
+        _add_config(src, "ring-64", "ring", {"m": 4096}, {"m": 64})]
+    _add_mix(src, "short_traces",
+             {"entry": "run_traces", "loop": "closed", "batch": 256,
+              "steps": 5, "max_branches": 8, "policy": "random",
+              "check_rows_per_call": 8}, {"batch": 8})
+    _add_mix(src, "even_singles",
+             {"entry": "single_traces", "loop": "open", "arrival": "even",
+              "rate_per_s": 200, "steps": 6, "max_branches": 4},
+             {"rate_per_s": 20})
     (bench / "metrics" / "calls_seen.py").write_text(
         "def read(r):\n    return r.trace['window_s'] or None\n")
     (bench / "generators" / "ring.py").write_text(RING)
     (bench / "arrivals" / "even.py").write_text(EVEN)
     (bench / "entries" / "single_traces.py").write_text(SINGLE)
-    (bench / "configs" / "ring-64.json").write_text(json.dumps({
-        "name": "ring-64", "generator": "ring", "args": {"m": 64},
-        "reduced": []}))
-    (bench / "traffic" / "even_singles.json").write_text(json.dumps({
-        "entry": "single_traces", "loop": "open", "arrival": "even",
-        "rate_per_s": 20, "steps": 6, "max_branches": 4}))
-    cells = json.loads((root / "BENCHMARK.json").read_text())
-    cells["configs"] += [
-        {"name": "powerlaw-300", "source": "test",
-         "file": "bench/configs/powerlaw-300.json", "reduced": [],
-         "why": "test"},
-        {"name": "ring-64", "source": "test",
-         "file": "bench/configs/ring-64.json", "reduced": [], "why": "test"}]
-    cells["workloads"] += [
-        {"name": "pl300.short", "config": "powerlaw-300",
-         "traffic": "short_traces", "chips": 1, "why": "test"},
-        {"name": "ring.singles", "config": "ring-64",
-         "traffic": "even_singles", "chips": 1, "why": "test"}]
+    cells["workloads"] += [_cell("pl300.short", "powerlaw-300",
+                                 "short_traces"),
+                           _cell("ring.singles", "ring-64", "even_singles")]
     next(m for m in cells["end_to_end"]
          if m["name"] == "trace_steps_per_s")["workloads"].append(
              "pl300.short")
@@ -185,21 +208,58 @@ def test_new_config_mix_and_metric_are_new_files_only(tmp_path,
         "name": "calls_seen", "unit": "s", "better": "higher",
         "source": "device_trace", "layer": "device",
         "moves": "trace_steps_per_s", "workloads": ["pl300.short"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(cells))
+    (src / "BENCHMARK.json").write_text(json.dumps(cells))
+    assert {p: b for p, b in _snapshot(src).items() if p in before} == before
 
-    assert {p: p.read_bytes() for p in before} == before
+    root = benchkit.tiny_root(tmp_path / "checkout", src=src)
+    plain = benchkit.tiny_root(tmp_path / "plain")
+    files = {c["name"]: c["file"] for c in SPEC["configs"]}
+    for cell in SPEC["workloads"]:
+        for path in (files[cell["config"]],
+                     f"bench/traffic/{cell['traffic']}.json"):
+            assert (root / path).read_bytes() == (plain / path).read_bytes()
+    assert spec.load_config(cells, root, "ring-64")["args"] == {"m": 64}
+    assert spec.load_mix(root, "short_traces")["batch"] == 8
     cell = spec.find_cell(cells, "pl300.short")
     assert [m["name"] for m in spec.per_layer_for(cells, cell)] == \
         ["compile_s", "calls_seen"]
     read = spec.metric_reader(root, "calls_seen")
     assert read(SimpleNamespace(trace={"window_s": 2.5})) == 2.5
-    res = benchkit.run_tiny(root, "pl300.short", monkeypatch)
+    res = benchkit.run_tiny(root, "pl300.short")
     assert res["correct"] is True, res["checks"]
     assert set(res["metrics"]) == {"setup_s", "trace_steps_per_s"}
-    res = benchkit.run_tiny(root, "ring.singles", monkeypatch)
+    res = benchkit.run_tiny(root, "ring.singles")
     assert res["correct"] is True, res["checks"]
     assert res["attempted"] == 20
     assert set(res["metrics"]) == {"setup_s", "single_p95_ms"}
+    res = benchkit.run_tiny(root, "pl8k.traces")
+    assert res["correct"] is True, res["checks"]
+
+
+def test_cell_without_tiny_files_fails_alone(tmp_path):
+    """A configuration or mix without its tiny file fails the cells that
+    use it, naming the file; the tiny root is made and the other cells
+    run."""
+    src = benchkit.copy_checkout(tmp_path / "src")
+    cells = json.loads((src / "BENCHMARK.json").read_text())
+    cells["configs"].append(_add_config(src, "powerlaw-300", "power_law",
+                                        {"m": 300, "attach": 3}))
+    _add_mix(src, "long_traces", {
+        "entry": "run_traces", "loop": "closed", "batch": 4, "steps": 64,
+        "max_branches": 8, "policy": "random", "check_rows_per_call": 4})
+    cells["workloads"] += [
+        _cell("pl300.traces", "powerlaw-300", "traces"),
+        _cell("pl8k.long", "powerlaw-8k-hubs", "long_traces")]
+    (src / "BENCHMARK.json").write_text(json.dumps(cells))
+
+    root = benchkit.tiny_root(tmp_path / "checkout", src=src)
+    for workload, missing in (
+            ("pl300.traces", "tests/bench/tiny/configs/powerlaw-300.json"),
+            ("pl8k.long", "tests/bench/tiny/traffic/long_traces.json")):
+        with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+            benchkit.run_tiny(root, workload)
+    res = benchkit.run_tiny(root, "pl8k.traces")
+    assert res["correct"] is True, res["checks"]
 
 
 def test_unknown_names_are_errors():
