@@ -44,7 +44,7 @@ def paper_mode_explore(system: SNPSystem, max_steps: int,
     multiplies one vector at a time."""
     comp = compile_system(system)
     m_mat = comp.M.astype(jnp.float32)
-    rules = [system.rules[i] for i in comp.rule_order]
+    rules = [system.rules[i] for i in np.asarray(comp.rule_order)]
     seen = {tuple(system.initial_spikes)}
     frontier = [tuple(system.initial_spikes)]
     for _ in range(max_steps):

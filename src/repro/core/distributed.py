@@ -66,8 +66,8 @@ from jax import shard_map
 from .backend import (BackendLike, PallasBackend, SparsePallasBackend,
                       compile_with_plan, lower_with_backend, resolve_entry,
                       resolve_entry_info, supports_sharded)
-from .engine import (ExploreResult, TraceOut, _traces_scan,
-                     successors_per_step)
+from .engine import (ExploreResult, TraceOut, _state_width, _traces_scan,
+                     trace_inits, trace_wait_args)
 from .failover import run_with_failover
 from .hashing import SENTINEL, config_hash, zobrist_hash
 from .hashtable import (HashTable, _base_slot, _canonical, first_occurrence,
@@ -790,6 +790,7 @@ def run_traces_distributed(
     backend: Optional[BackendLike] = None,
     mesh: Optional[Mesh] = None,
     plan: Optional[SystemPlan] = None,
+    init=None,
 ):
     """Mesh-sharded :func:`repro.core.engine.run_traces` (DESIGN.md §4).
 
@@ -800,7 +801,8 @@ def run_traces_distributed(
     only on ``seeds[b]``, so the result is **bit-identical** to the
     single-device :func:`~repro.core.engine.run_traces` — padding the batch
     up to a mesh multiple (with seed-0 dummies, sliced off on return) is
-    therefore free.
+    therefore free.  ``init`` is as in ``run_traces``: ``None``, one
+    ``(m,)`` start for every trace, or ``(B, m)`` one per trace.
 
     Returns a :class:`~repro.core.engine.TraceOut` of ``(configs
     (B, steps, m), emissions (B, steps), alive (B, steps),
@@ -819,14 +821,15 @@ def run_traces_distributed(
     # The planner decides when backend=None (default SystemPlan mode
     # "auto"); _traces_shard_fn's lru cache keys on the resolved backend
     # *instance*, so a plan kernel's block shape is part of the key.
-    with TraceAnnotation("snp.traces", batch=int(seeds.shape[0])):
+    B = seeds.shape[0]
+    init_rows = 1 if np.ndim(init) < 2 else np.shape(init)[0]
+    with TraceAnnotation("snp.traces", batch=B, init_rows=init_rows):
         be, plan, planned = resolve_entry_info(
-            system, backend, plan,
-            workload=(int(seeds.shape[0]), max_branches))
+            system, backend, plan, workload=(B, max_branches))
+        init = trace_inits(init, B, _state_width(system, plan))
         mesh, axis = _flat_mesh(mesh)
         ndev = mesh.devices.size
 
-        B = seeds.shape[0]
         Bp = ((max(B, 1) + ndev - 1) // ndev) * ndev
         padded = np.zeros((Bp,), np.uint32)
         padded[:B] = seeds
@@ -836,13 +839,14 @@ def run_traces_distributed(
             comp = lower_with_backend(be, system, plan) \
                 if is_compiled(system) \
                 else compile_with_plan(be, system, plan)
-            c0s = jnp.broadcast_to(comp.init_config,
-                                   (Bp,) + comp.init_config.shape)   # (Bp, m)
+            c0s = jnp.broadcast_to(comp.init_config if init is None
+                                   else init, (B,) + comp.init_config.shape)
+            c0s = jnp.pad(c0s, ((0, Bp - B), (0, 0)))               # (Bp, m)
             fn = _traces_shard_fn(mesh, axis, steps, max_branches, policy, be)
-            out = fn(comp, c0s, keys)
+            out = fn(comp, keys, c0s)
             with TraceAnnotation(
                     "snp.traces.wait",
-                    successors=successors_per_step(be, max_branches)):
+                    **trace_wait_args(be, comp, max_branches)):
                 jax.block_until_ready(out.configs)
             return out
 

@@ -58,6 +58,7 @@ from .hashtable import (HashTable, first_occurrence, insert_unique, lookup,
                         make_table)
 from .matrix import CompiledAny, is_compiled
 from .plan import SystemPlan
+from .semantics import fired_lookup
 from .system import SNPSystem
 
 __all__ = ["ExploreState", "ExploreResult", "TraceOut", "explore",
@@ -118,9 +119,13 @@ class ExploreResult:
         return ["-".join(str(int(v)) for v in row) for row in self.configs]
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("frontier_cap", "visited_cap", "dedup"))
 def _init_state(comp: CompiledAny, frontier_cap: int, visited_cap: int,
                 init: Optional[jnp.ndarray] = None,
                 dedup: str = "hash") -> ExploreState:
+    # One program per shape: run eagerly, the hash table's first insert
+    # compiled a new ``while`` every call.
     # State row width: m for the paper's systems, 3m under delayed
     # semantics ([spikes | countdown | pending] — DESIGN.md).
     m = getattr(comp, "state_width", comp.num_neurons)
@@ -578,21 +583,71 @@ def successors_per_step(backend, max_branches: int) -> int:
     return 1 if hasattr(backend, "step_chosen") else max_branches
 
 
+def _rule_slots(comp: CompiledAny) -> int:
+    """``R``, the most rules one neuron holds."""
+    if hasattr(comp, "rule_slots"):
+        return int(comp.rule_slots.shape[0])
+    return int(np.bincount(np.asarray(comp.rule_neuron)).max())
+
+
+def trace_wait_args(backend, comp: CompiledAny, max_branches: int) -> dict:
+    """Arguments of the ``snp.traces.wait`` span: the successors a
+    trace-step builds per trace, how it finds each neuron's fired rule
+    (``"rules"`` or ``"table"``, :func:`~repro.core.semantics.fired_lookup`)
+    and the rule slots ``R``."""
+    successors = successors_per_step(backend, max_branches)
+    return dict(successors=successors,
+                fired=fired_lookup(comp, successors),
+                rule_slots=_rule_slots(comp))
+
+
+def trace_inits(init, batch: int, width: int) -> Optional[jnp.ndarray]:
+    """``init`` as int32 rows for a batch of ``batch`` traces of state
+    width ``width``: ``None`` stays ``None`` (the system's initial
+    configuration), ``(width,)`` is every trace's start, ``(batch,
+    width)`` one start per trace; any other shape raises."""
+    if init is None:
+        return None
+    # asarray first: numpy rows become int32 on the host, where
+    # asarray(init, int32) compiles a device convert for every new shape
+    init = jnp.asarray(init).astype(jnp.int32)
+    if init.shape not in ((width,), (batch, width)):
+        raise ValueError(
+            f"init must be ({width},) or ({batch}, {width}) for {batch} "
+            f"traces of {width} columns, got shape {init.shape}")
+    return init
+
+
+def _state_width(system, plan: Optional[SystemPlan]) -> int:
+    """Columns of one configuration row of ``system`` as ``plan`` lowers
+    it: ``m``, or ``3m`` under the delayed semantics."""
+    if is_compiled(system):
+        return system.state_width
+    delayed = getattr(plan, "semantics", "no_delays") == "delays"
+    return system.num_neurons * (3 if delayed else 1)
+
+
 @functools.partial(
     jax.jit, static_argnames=("steps", "max_branches", "policy", "backend"))
-def _traces_scan(comp, c0s, keys, steps, max_branches, policy, backend):
+def _traces_scan(comp, keys, init=None, *, steps, max_branches, policy,
+                 backend):
     """B independent trajectories, one ``lax.scan`` over time.
 
-    ``c0s`` (B, m), ``keys`` (B, 2) — per-trace PRNG streams, split exactly
-    as the single-trace path splits its key, so trace b depends only on
-    ``keys[b]`` and batching never changes a trajectory.
+    ``keys`` (B, 2) — per-trace PRNG streams, split exactly as the
+    single-trace path splits its key, so trace b depends only on
+    ``keys[b]`` and batching never changes a trajectory.  ``init`` is
+    ``None`` (every trace starts at ``comp.init_config``), one ``(m,)``
+    start for every trace, or ``(B, m)`` one per trace (checked by
+    :func:`trace_inits`).
 
     The branch index depends only on the branch count and the key, so a
     backend with ``step_chosen`` draws it first and builds one successor
     per trace; any other backend expands all ``T`` candidates and keeps
     the one at that index.  Both give the same trajectories.
     """
-    B = c0s.shape[0]
+    B = keys.shape[0]
+    c0s = jnp.broadcast_to(comp.init_config if init is None else init,
+                           (B,) + comp.init_config.shape)
 
     def body(carry, _):
         cfgs, keys = carry
@@ -638,18 +693,24 @@ def run_traces(
     policy: str = "first", max_branches: int = 64,
     backend: Optional[BackendLike] = None,
     plan: Optional[SystemPlan] = None,
+    init=None,
 ):
     """Batched trajectory serving: B independent paths in one jitted scan.
 
     Returns a :class:`TraceOut` — ``(configs (B, steps, m), emissions
     (B, steps), alive (B, steps), branch_overflow (B, steps))`` with
-    ``B = len(seeds)``.  Row b is bit-identical to
-    ``run_trace(..., seed=seeds[b])`` with the same policy/backend — one
-    transition per step for the whole batch, which is the serving-path hot
-    loop.  Each step builds only the successor each trace keeps where the
-    backend has ``step_chosen`` (``"sparse"``), and all ``max_branches``
-    candidates through ``expand`` otherwise; the ``snp.traces.wait`` span
-    records which as ``successors`` per trace-step.
+    ``B = len(seeds)``.  ``init`` is where the traces start: ``None`` (the
+    system's initial configuration), one ``(m,)`` configuration for every
+    trace, or ``(B, m)`` one per trace; any other shape raises.  Row b is
+    bit-identical to ``run_trace(..., seed=seeds[b], init=init[b])`` with
+    the same policy/backend — one transition per step for the whole batch,
+    which is the serving-path hot loop.  Each step builds only the
+    successor each trace keeps where the backend has ``step_chosen``
+    (``"sparse"``), and all ``max_branches`` candidates through ``expand``
+    otherwise; the ``snp.traces.wait`` span records which as
+    ``successors`` per trace-step, with ``fired`` and ``rule_slots``
+    (:func:`trace_wait_args`), and the ``snp.traces`` span the rows of
+    ``init`` as ``init_rows`` (1, or B for one start per trace).
     ``backend=None`` (the default) hands the choice to the query planner
     under the default ``SystemPlan(mode="auto")`` — see :func:`explore`;
     traces are backend-independent, so the planner only moves wall-time,
@@ -661,24 +722,30 @@ def run_traces(
     seeds = jnp.asarray(seeds, jnp.uint32)
     if seeds.ndim != 1:
         raise ValueError(f"seeds must be 1-D, got shape {seeds.shape}")
-    with TraceAnnotation("snp.traces", batch=int(seeds.shape[0])):
+    B = int(seeds.shape[0])
+    init_rows = 1 if np.ndim(init) < 2 else np.shape(init)[0]
+    with TraceAnnotation("snp.traces", batch=B, init_rows=init_rows):
         be, plan, planned = resolve_entry_info(
-            system, backend, plan,
-            workload=(int(seeds.shape[0]), max_branches))
+            system, backend, plan, workload=(B, max_branches))
         if plan is not None and plan.num_shards > 1:
             # caller error: raise, don't degrade
             _resolve_comp(system, be, plan)
+        init = trace_inits(init, B, _state_width(system, plan))
         keys = jax.vmap(jax.random.PRNGKey)(seeds)             # (B, 2)
 
         def attempt(be, plan):
             comp = _resolve_comp(system, be, plan)
-            c0s = jnp.broadcast_to(comp.init_config, (seeds.shape[0],) +
-                                   comp.init_config.shape)
-            out = _traces_scan(comp, c0s, keys, steps, max_branches, policy,
-                               be)
+            # one (B, m) row per trace whatever ``init`` is: every form
+            # runs the same compiled scan
+            c0s = jnp.broadcast_to(
+                comp.init_config if init is None else init,
+                (B,) + comp.init_config.shape)
+            out = _traces_scan(comp, keys, c0s, steps=steps,
+                               max_branches=max_branches, policy=policy,
+                               backend=be)
             with TraceAnnotation(
                     "snp.traces.wait",
-                    successors=successors_per_step(be, max_branches)):
+                    **trace_wait_args(be, comp, max_branches)):
                 # first-run failures degrade too
                 jax.block_until_ready(out.configs)
             return out
@@ -691,16 +758,21 @@ def run_trace(
     policy: str = "first", seed: int = 0, max_branches: int = 64,
     backend: Optional[BackendLike] = None,
     plan: Optional[SystemPlan] = None,
+    init=None,
 ):
     """Single-path simulation (deterministic or uniformly random branch).
 
     Returns a :class:`TraceOut` of (configs (steps, m), emissions
-    (steps,), alive (steps,), branch_overflow (steps,)).
+    (steps,), alive (steps,), branch_overflow (steps,)), from ``init``
+    (an ``(m,)`` configuration; ``None``: the system's initial one).
     The 'serving' mode of the engine: one trajectory, spike train out.
     Implemented as a B=1 :func:`run_traces` batch, so the single- and
     batched-serving paths can never drift apart.
     """
+    if init is not None and np.ndim(init) != 1:
+        raise ValueError(f"init of one trace must be 1-D, got shape "
+                         f"{np.shape(init)}")
     out = run_traces(
         system, steps=steps, seeds=[seed], policy=policy,
-        max_branches=max_branches, backend=backend, plan=plan)
+        max_branches=max_branches, backend=backend, plan=plan, init=init)
     return TraceOut(*(x[0] for x in out))

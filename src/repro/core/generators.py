@@ -27,6 +27,12 @@ unusable past a few thousand neurons):
 * ``power_law``       — preferential attachment: bounded *mean* degree
                         with heavy-tailed in-degree, the adversarial case
                         for ELL row packing.
+
+From the literature:
+
+* ``sorting``         — the sorting system of Ionescu and Sburlan (2008):
+                        ``n² + n`` rules, ``n`` of them on each middle
+                        neuron, so the rule axis dominates the step.
 """
 
 from __future__ import annotations
@@ -38,7 +44,7 @@ from typing import Callable, Optional, Sequence, Tuple, Union
 from .system import Rule, SNPSystem
 
 __all__ = ["ring", "nd_chain", "random_system", "counter", "scaled_pi",
-           "ring_lattice", "torus", "power_law", "with_delays"]
+           "ring_lattice", "torus", "power_law", "sorting", "with_delays"]
 
 
 def ring(m: int, produce: int = 1) -> SNPSystem:
@@ -279,6 +285,41 @@ def power_law(m: int, attach: int = 4, rules_per_neuron: int = 2,
     cap = "" if max_in is None else f"c{max_in}"
     return _sparse_family(f"power-law-{m}a{attach}{cap}", m, syn,
                           rules_per_neuron, max_spikes, seed)
+
+
+def sorting(n: int, values: Optional[Sequence[int]] = None) -> SNPSystem:
+    """The sorting SN P system of M. Ionescu and D. Sburlan, "Some
+    applications of spiking neural P systems", Computing and Informatics
+    27(3):515-528 (2008), on ``n`` naturals.
+
+    Neurons ``0..n-1`` are the inputs: input ``i`` holds ``values[i]``
+    spikes (default 0) and has ``a+/a -> a``.  Neurons ``n..2n-1`` are the
+    middle layer: neuron ``n + j - 1`` has ``a^j -> a`` and ``a^k -> λ``
+    for every other ``k`` in ``1..n`` (rules in ``k`` order).  Neurons
+    ``2n..3n-1`` are the outputs and have no rules.  Every input synapses
+    onto every middle neuron, and middle neuron ``n + j - 1`` onto outputs
+    ``2n..2n+j-1``.
+
+    Deterministic: while ``c`` inputs still hold spikes, the middle neuron
+    ``n + c - 1`` fires the next step and feeds the first ``c`` outputs, so
+    after ``max(values) + 1`` steps output ``2n + k - 1`` holds the
+    ``k``-th largest value."""
+    if n < 1:
+        raise ValueError(f"need n >= 1, got {n}")
+    values = (0,) * n if values is None else tuple(int(v) for v in values)
+    if len(values) != n:
+        raise ValueError(f"values has {len(values)} entries, expected {n}")
+    rules = [Rule(neuron=i, consume=1, produce=1, regex_base=1,
+                  regex_period=1) for i in range(n)]
+    for j in range(1, n + 1):
+        rules += [Rule(neuron=n + j - 1, consume=k,
+                       produce=1 if k == j else 0, regex_base=k)
+                  for k in range(1, n + 1)]
+    syn = [(i, n + j) for i in range(n) for j in range(n)]
+    syn += [(n + j - 1, 2 * n + k) for j in range(1, n + 1)
+            for k in range(j)]
+    return SNPSystem(3 * n, values + (0,) * (2 * n), tuple(rules),
+                     tuple(syn), name=f"sort-{n}")
 
 
 # ---------------------------------------------------------------------------

@@ -7,13 +7,14 @@ Two lowerings of an :class:`~repro.core.system.SNPSystem`, both with rules
   ``M_Π`` (:class:`CompiledSNP`); ``O(n·m)`` memory, exact match for the
   paper's eq. 2 formulation.
 * :func:`compile_system_sparse` — an ELL/segment encoding
-  (:class:`CompiledSparseSNP`) that never materializes ``M_Π``: per-rule
-  ELL-packed column indices/values (width = the *measured*
-  ``max_nnz_per_rule``), per-neuron rule segments, and the ELL-packed
-  in-adjacency of the synapse graph.  Real SNP graphs have bounded synapse
-  out-degree, so ``nnz(M_Π) = O(n·degree)`` while the dense matrix is
-  ``O(n·m)`` — the sparse step backends (``"sparse"``, ``"sparse_pallas"``)
-  run on this encoding in ``O(B·T·m·degree)`` instead of ``O(B·T·n·m)``.
+  (:class:`CompiledSparseSNP`) that never materializes ``M_Π``: the
+  per-rule vectors, per-neuron rule segments, and the ELL-packed
+  in-adjacency of the synapse graph.  A fired rule's row of ``M_Π`` is
+  ``-consume`` at its owner plus ``produce`` on the owner's out-neighbours,
+  so these rebuild ``M_Π`` exactly, in ``O(n + m·K_in)`` memory where the
+  dense matrix is ``O(n·m)`` — the sparse step backends (``"sparse"``,
+  ``"sparse_pallas"``) run on this encoding in ``O(B·T·m·degree)`` instead
+  of ``O(B·T·n·m)``.
   With ``hub_threshold=H`` (requested through a
   :class:`~repro.core.plan.SystemPlan` with ``encoding="hybrid"``) the ELL
   in-adjacency is capped at ``H`` entries per neuron and the tail synapses
@@ -28,7 +29,7 @@ neurons compile in well under a second.
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Tuple, Union
+from typing import List, NamedTuple, Union
 
 import jax.numpy as jnp
 import numpy as np
@@ -86,7 +87,9 @@ class CompiledSNP(NamedTuple):
     neuron_onehot: jnp.ndarray  # (n, m) int8 — rule->neuron incidence
     env_produce: jnp.ndarray    # (n,)  int32 — spikes emitted to environment
     init_config: jnp.ndarray    # (m,) int32 — C_0 (3m under delays)
-    rule_order: Tuple[int, ...]  # original rule index per sorted position
+    rule_order: jnp.ndarray     # (n,)  int32 — original rule index per
+    #                             sorted position (an array, so a jitted
+    #                             call carries one leaf for it, not n)
     # -- delayed-semantics extension (None == no_delays encoding) ---------
     delay: jnp.ndarray = None       # (n,) int32 — per-rule firing delay
     adjacency: jnp.ndarray = None   # (m, m) int32 — 0/1 synapse matrix
@@ -115,8 +118,7 @@ class CompiledSparseSNP(NamedTuple):
     """ELL/segment device-array encoding of an SNP system — no ``O(n·m)``
     arrays anywhere (DESIGN.md §3).
 
-    Shapes: ``m`` neurons, ``n`` rules (sorted by neuron), ``K`` =
-    ``max_nnz_per_rule`` (measured at compile time), ``R`` =
+    Shapes: ``m`` neurons, ``n`` rules (sorted by neuron), ``R`` =
     ``max_rules_per_neuron``, ``Kin`` = max synapse in-degree (>= 1).
 
     Padding convention: index entries beyond a row's real length point at
@@ -134,16 +136,12 @@ class CompiledSparseSNP(NamedTuple):
     env_produce: jnp.ndarray    # (n,)  int32
     init_config: jnp.ndarray    # (m,)  int32
     out_neuron: jnp.ndarray     # ()    int32 — output neuron, or m if none
-    rule_order: Tuple[int, ...]
+    rule_order: jnp.ndarray     # (n,)  int32
     # -- per-neuron rule segments (rules are neuron-sorted) ---------------
     seg_start: jnp.ndarray      # (m,) int32 — first rule index of neuron
     seg_count: jnp.ndarray      # (m,) int32 — #rules owned by neuron
     rule_slots: jnp.ndarray     # (R,) int32 == arange(R); carries R in its
     #                             shape so traced code can size tables
-    # -- ELL rows of M_Π ---------------------------------------------------
-    ell_col: jnp.ndarray        # (n, K) int32 — column (target neuron), pad m
-    ell_val: jnp.ndarray        # (n, K) int32 — value, pad 0
-    ell_nnz: jnp.ndarray        # (n,)  int32 — real row lengths
     # -- ELL in-adjacency of the synapse graph ----------------------------
     in_idx: jnp.ndarray         # (m, Kin) int32 — in-neighbors, pad m
     # -- COO tail of the in-adjacency (hybrid encoding; empty for pure ELL)
@@ -176,10 +174,6 @@ class CompiledSparseSNP(NamedTuple):
         """Columns of one configuration row: ``m``, or ``3m`` under the
         delayed semantics (``[spikes | countdown | pending]``)."""
         return self.init_config.shape[0]
-
-    @property
-    def max_nnz_per_rule(self) -> int:
-        return self.ell_col.shape[1]
 
     @property
     def max_rules_per_neuron(self) -> int:
@@ -234,7 +228,7 @@ def _ragged_arange(counts: np.ndarray) -> np.ndarray:
 class _Lowered(NamedTuple):
     """Neuron-sorted rule arrays + synapse adjacency, all numpy."""
 
-    order: Tuple[int, ...]
+    order: np.ndarray         # (n,) i64 — original index per sorted rule
     rules: List[Rule]
     neuron: np.ndarray        # (n,) i32
     consume: np.ndarray       # (n,) i32
@@ -275,7 +269,7 @@ def _lower(system: SNPSystem) -> _Lowered:
     out_deg = np.bincount(src, minlength=m)
     out_start = np.cumsum(out_deg) - out_deg
 
-    return _Lowered(order=tuple(int(i) for i in order), rules=rules,
+    return _Lowered(order=order, rules=rules,
                     neuron=neuron, consume=consume, produce=produce,
                     regex_base=regex_base, regex_period=regex_period,
                     covering=covering, env_produce=env_produce,
@@ -288,10 +282,8 @@ def _rule_row_entries(low: _Lowered):
 
     Rule ``i`` (neuron-sorted) with ``produce > 0`` writes ``produce`` into
     every out-neighbor column of its neuron; the consume entry (its own
-    neuron, value ``-consume``) is handled separately by each caller.
-    Returns ``(rows, pos, cols, vals)`` with ``pos`` the within-row slot.
+    neuron, value ``-consume``) is handled separately.
     """
-    n = low.neuron.shape[0]
     prod_rules = np.nonzero(low.produce > 0)[0]
     deg_r = low.out_deg[low.neuron[prod_rules]]
     rows = np.repeat(prod_rules, deg_r)
@@ -299,8 +291,7 @@ def _rule_row_entries(low: _Lowered):
     flat = np.repeat(low.out_start[low.neuron[prod_rules]], deg_r) + pos
     cols = low.dst[flat] if rows.size else np.zeros((0,), np.int32)
     vals = np.repeat(low.produce[prod_rules], deg_r)
-    return rows.astype(np.int64), pos, cols, vals.astype(np.int32), \
-        prod_rules, deg_r
+    return rows.astype(np.int64), cols, vals.astype(np.int32)
 
 
 def _delay_vector(low: _Lowered) -> np.ndarray:
@@ -331,7 +322,7 @@ def compile_system(system: SNPSystem, *,
 
     M = np.zeros((n, m), dtype=np.int32)
     M[np.arange(n), low.neuron] = -low.consume
-    rows, _, cols, vals, _, _ = _rule_row_entries(low)
+    rows, cols, vals = _rule_row_entries(low)
     M[rows, cols] = vals  # no collisions: self-synapses are forbidden
 
     onehot = np.zeros((n, m), dtype=np.int8)
@@ -361,7 +352,7 @@ def compile_system(system: SNPSystem, *,
         neuron_onehot=jnp.asarray(onehot),
         env_produce=jnp.asarray(low.env_produce),
         init_config=jnp.asarray(init, dtype=jnp.int32),
-        rule_order=low.order,
+        rule_order=jnp.asarray(low.order, jnp.int32),
         **extra,
     )
 
@@ -370,9 +361,9 @@ def compile_system_sparse(system: SNPSystem, *,
                           hub_threshold: int | None = None,
                           semantics: str = "no_delays"
                           ) -> CompiledSparseSNP:
-    """Sparse lowering: ELL rows of ``M_Π`` + per-neuron segments + ELL
+    """Sparse lowering: per-rule vectors + per-neuron segments + ELL
     in-adjacency.  Never allocates anything ``O(n·m)``; memory and compile
-    time are ``O(n·K + m·Kin)`` with measured widths.
+    time are ``O(n + m·Kin)`` with the measured in-degree.
 
     ``hub_threshold=H`` selects the **hybrid** in-adjacency: the ELL part
     is capped at ``H`` entries per neuron and every further in-synapse of a
@@ -387,7 +378,7 @@ def compile_system_sparse(system: SNPSystem, *,
     same ELL/COO in-adjacency as the fired produce, so the layout gains
     no new index arrays (DESIGN.md §2 "Delayed semantics")."""
     delayed = _check_semantics(system, semantics)
-    m, n = system.num_neurons, system.num_rules
+    m = system.num_neurons
     low = _lower(system)
 
     # The sparse step packs (produce, consume) of a fired rule into one
@@ -403,18 +394,6 @@ def compile_system_sparse(system: SNPSystem, *,
     seg_count = np.bincount(low.neuron, minlength=m).astype(np.int32)
     seg_start = (np.cumsum(seg_count) - seg_count).astype(np.int32)
     R = int(max(seg_count.max(), 1))
-
-    # -- ELL rows of M: slot 0 is the consume entry, 1.. the produce fanout
-    rows, pos, cols, vals, prod_rules, deg_r = _rule_row_entries(low)
-    K = int(1 + (deg_r.max() if deg_r.size else 0))
-    ell_col = np.full((n, K), m, dtype=np.int32)
-    ell_val = np.zeros((n, K), dtype=np.int32)
-    ell_col[:, 0] = low.neuron
-    ell_val[:, 0] = -low.consume
-    ell_col[rows, 1 + pos] = cols
-    ell_val[rows, 1 + pos] = vals
-    ell_nnz = np.ones((n,), np.int32)
-    ell_nnz[prod_rules] += deg_r.astype(np.int32)
 
     # -- ELL in-adjacency (transposed synapse graph) ----------------------
     # Entries sorted by (target, source); a ragged arange over the in-degree
@@ -458,13 +437,10 @@ def compile_system_sparse(system: SNPSystem, *,
         out_neuron=jnp.asarray(
             system.output_neuron if system.output_neuron >= 0 else m,
             dtype=jnp.int32),
-        rule_order=low.order,
+        rule_order=jnp.asarray(low.order, jnp.int32),
         seg_start=jnp.asarray(seg_start),
         seg_count=jnp.asarray(seg_count),
         rule_slots=jnp.arange(R, dtype=jnp.int32),
-        ell_col=jnp.asarray(ell_col),
-        ell_val=jnp.asarray(ell_val),
-        ell_nnz=jnp.asarray(ell_nnz),
         in_idx=jnp.asarray(in_idx),
         coo_src=jnp.asarray(coo_src),
         coo_dst=jnp.asarray(coo_dst),

@@ -18,7 +18,10 @@ run the same math on the ELL/segment encoding
 bit-identical valid entries — see DESIGN.md §3.  The chosen-successor
 twins (:func:`sparse_chosen_config`, :func:`sparse_delayed_chosen_config`)
 count the branches first, let the caller pick one per row, and build only
-that successor: the trace scan keeps one of the ``T`` anyway.
+that successor: the trace scan keeps one of the ``T`` anyway.  On systems
+with more than :data:`TABLE_MAX_RULE_SLOTS` rules on a neuron they find
+each neuron's fired rule in rule space, so neither program nor memory
+grows with the rules a neuron holds.
 
 Enumeration order.  Neuron 0 is the most-significant mixed-radix digit:
 branch index ``t ∈ [0, Ψ)`` decodes to ``digit_i = (t // stride_i) % k_i``
@@ -54,6 +57,8 @@ __all__ = [
     "sparse_next_configs",
     "sparse_chosen_config",
     "in_adjacency_sum",
+    "TABLE_MAX_RULE_SLOTS",
+    "fired_lookup",
     "StepOut",
     "ChosenOut",
     "split_state",
@@ -296,6 +301,60 @@ def packed_rule_table(info: BranchInfo, comp: CompiledSparseSNP,
     return jnp.stack(cols, axis=-1).reshape(*batch, m, R)
 
 
+# Most rules on one neuron (``R``) for which a one-successor step looks
+# the fired rules up in the (B, m, R) table; above it the step selects
+# them in rule space (:func:`_fired_by_rules`).  Both are exact; the table
+# costs O(B·m·R²) and an R-fold unrolled program, the rule-space select
+# one more rule-axis prefix sum.  DESIGN.md §3 has the cells either side.
+TABLE_MAX_RULE_SLOTS = 8
+
+
+def fired_lookup(comp: CompiledAny, branches: int) -> str:
+    """How a step that builds ``branches`` successors per row finds each
+    neuron's fired rule: ``"rules"`` (rule space: the dense one-hot
+    spiking vectors, or :func:`_fired_by_rules`) or ``"table"``
+    (:func:`packed_rule_table`)."""
+    if not isinstance(comp, CompiledSparseSNP):
+        return "rules"
+    R = comp.rule_slots.shape[0]
+    return "rules" if branches == 1 and R > TABLE_MAX_RULE_SLOTS \
+        else "table"
+
+
+def _fired_by_rules(info: BranchInfo, comp: CompiledSparseSNP,
+                    digits: jnp.ndarray, packed: jnp.ndarray
+                    ) -> jnp.ndarray:
+    """The per-rule (n,) int32 ``packed`` payload of the rule each neuron
+    fires at ``digits`` (B, T', m), as (B, T', m), found in rule space.
+
+    Rule ``r`` fires iff it is applicable and its rank is its neuron's
+    digit; a neuron fires at most one rule, so its payload is the sum over
+    its segment of the neuron-sorted rules: a difference of one inclusive
+    prefix sum at the segment ends.  The int32 sums may wrap, but each
+    difference is one payload below 2^31, so it is exact.  ``O(B·T'·n)``,
+    the order of :func:`applicability`, whatever ``R`` is."""
+    digit_r = jnp.take(digits, comp.rule_neuron, axis=-1)    # (B, T', n)
+    fired = info.app[:, None, :] & (info.rank[:, None, :] == digit_r)
+    cum = jnp.cumsum(jnp.where(fired, packed, 0), axis=-1)
+    cum0 = jnp.concatenate(
+        [jnp.zeros_like(cum[..., :1]), cum], axis=-1)        # (B, T', n+1)
+    return (jnp.take(cum0, comp.seg_start + comp.seg_count, axis=-1)
+            - jnp.take(cum0, comp.seg_start, axis=-1))
+
+
+def _fired_payload(info: BranchInfo, comp: CompiledSparseSNP,
+                   digits: jnp.ndarray, packed: jnp.ndarray = None
+                   ) -> jnp.ndarray:
+    """(B, T', m) payload of each neuron's fired rule at ``digits``: in
+    rule space where :func:`fired_lookup` says so, else through the
+    packed rule table."""
+    if packed is None:
+        packed = comp.produce | (comp.consume << 16)         # (n,)
+    if fired_lookup(comp, digits.shape[-2]) == "rules":
+        return _fired_by_rules(info, comp, digits, packed)
+    return _fired_packed(digits, packed_rule_table(info, comp, packed))
+
+
 def _decode_digits(t: jnp.ndarray, info: BranchInfo) -> jnp.ndarray:
     """Mixed-radix digit per (branch, neuron): ``(t // stride) % choices``
     as (B, T', m) int32, computed in float32.  ``t`` is ``(T',)`` branch
@@ -394,9 +453,8 @@ def _sparse_successors(cfg: jnp.ndarray, info: BranchInfo,
     """Successors of the (B, m) rows ``cfg`` at branch indices ``t`` —
     ``(T',)`` shared or ``(B, T')`` per row — as ``(configs (B, T', m),
     emissions (B, T'))``.  Steps 1–4 of :func:`sparse_next_configs`."""
-    tab = packed_rule_table(info, comp)                      # (B, m, R)
     digits = _decode_digits(t, info)                         # (B, T', m)
-    packed_f = _fired_packed(digits, tab)                    # (B, T', m)
+    packed_f = _fired_payload(info, comp, digits)            # (B, T', m)
     prod_f = packed_f & 0xFFFF
     cons_f = packed_f >> 16
 
@@ -475,7 +533,9 @@ def sparse_chosen_config(config: jnp.ndarray, comp: CompiledSparseSNP,
     ``choose`` maps it to one branch index per row (traceable, < T).  The
     decode, fired-rule lookup and in-adjacency contraction then run on
     ``B`` rows instead of ``B·T``, with the same int32 arithmetic, so the
-    result is bit-identical to picking that row of the expansion."""
+    result is bit-identical to picking that row of the expansion.  Past
+    :data:`TABLE_MAX_RULE_SLOTS` rules on a neuron the fired rules are
+    selected in rule space (:func:`fired_lookup`)."""
     return _choose_then_step(config, comp, max_branches, choose,
                              sparse_branch_info, _sparse_successors)
 
@@ -627,14 +687,11 @@ def _sparse_delayed_successors(cfg: jnp.ndarray, info: BranchInfo,
     emissions (B, T'))``."""
     spikes, cd, pd = split_state(cfg)
     packed_e, packed_d = delayed_packed_actions(comp)
-    etab = packed_rule_table(info, comp, packed_e)           # (B, m, R)
-    dtab = packed_rule_table(info, comp, packed_d)
-
     digits = _decode_digits(t, info)                         # (B, T', m)
-    pe = _fired_packed(digits, etab)
+    pe = _fired_payload(info, comp, digits, packed_e)
     prod_now = pe & 0xFFFF
     cons_f = pe >> 16
-    pdl = _fired_packed(digits, dtab)
+    pdl = _fired_payload(info, comp, digits, packed_d)
     fired_del = pdl != 0
     prod_pend = pdl & 0xFFFF
     d_f = pdl >> 16

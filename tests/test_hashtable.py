@@ -310,3 +310,26 @@ def test_dedup_auto_bit_identical_to_both():
         np.testing.assert_array_equal(
             auto.configs[:auto.num_discovered],
             ref.configs[:ref.num_discovered])
+
+
+@pytest.mark.parametrize("dedup", ["hash", "sort"])
+def test_explore_call_from_a_new_init_compiles_nothing(dedup, caplog):
+    """A second ``explore`` call of the same shapes from another initial
+    configuration compiles nothing: the initial state, the hash table's
+    first insert included, is one program per shape."""
+    import logging
+
+    import jax
+    system = power_law(60, 3, seed=2)
+    kw = dict(max_steps=4, frontier_cap=8, visited_cap=512, max_branches=4,
+              backend="sparse", dedup=dedup)
+    init = np.asarray(system.initial_spikes)
+    explore(system, init=init, **kw)
+    jax.config.update("jax_log_compiles", True)
+    try:
+        with caplog.at_level(logging.WARNING, logger="jax"):
+            explore(system, init=init[::-1], **kw)
+    finally:
+        jax.config.update("jax_log_compiles", False)
+    assert not [r for r in caplog.records
+                if "Compiling" in r.getMessage()], caplog.text

@@ -43,7 +43,7 @@ def test_transition_matrix_matches_paper_eq1(comp_covering):
     )
     np.testing.assert_array_equal(np.asarray(comp_covering.M), expected)
     # the paper's total order is preserved (rules already neuron-sorted)
-    assert comp_covering.rule_order == (0, 1, 2, 3, 4)
+    assert tuple(np.asarray(comp_covering.rule_order)) == (0, 1, 2, 3, 4)
 
 
 def test_spiking_vectors_at_c0(comp_covering):

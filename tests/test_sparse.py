@@ -1,7 +1,7 @@
 """Sparse encoding + sparse step tests.
 
-Covers the compile layer (vectorized dense M vs. brute force, ELL/segment
-encoding round-trips, compile-time regression), the sparse step semantics
+Covers the compile layer (vectorized dense M vs. brute force, the sparse
+encoding rebuilding M, compile-time regression), the sparse step semantics
 (bit-identity with the dense oracle, including the edge cases a sparse
 path can get wrong: rules with zero synapses out, neurons with no rules,
 Ψ-overflow parity, n-d batches), and the fused sparse Pallas kernel's
@@ -18,7 +18,8 @@ from conftest import assert_same_step as _assert_same_step
 from repro.core import (compile_system, compile_system_sparse, explore,
                         get_backend, paper_pi, successor_set)
 from repro.core.generators import (counter, nd_chain, power_law,
-                                   random_system, ring, ring_lattice, torus)
+                                   random_system, ring, ring_lattice, sorting,
+                                   torus)
 from repro.core.semantics import next_configs, sparse_next_configs
 from repro.core.system import Rule, SNPSystem
 from repro.kernels.snp_step import snp_step_sparse
@@ -53,6 +54,26 @@ def _brute_force_M(system):
     return M, tuple(order)
 
 
+def _rebuild_M(sp):
+    """``M_Π`` from the sparse encoding alone: ``-consume`` at each rule's
+    owner, and the ``produce`` of every rule of neuron ``i`` at each
+    neuron that has ``i`` among its in-neighbours (the ELL rows of
+    ``in_idx``, then the COO tail), through the rule segments."""
+    n, m = sp.num_rules, sp.num_neurons
+    ss, sc = np.asarray(sp.seg_start), np.asarray(sp.seg_count)
+    produce = np.asarray(sp.produce)
+    M = np.zeros((n, m), np.int32)
+    M[np.arange(n), np.asarray(sp.rule_neuron)] = -np.asarray(sp.consume)
+    ii = np.asarray(sp.in_idx)
+    src = np.concatenate([ii.ravel(), np.asarray(sp.coo_src)])
+    dst = np.concatenate([np.repeat(np.arange(m), ii.shape[1]),
+                          np.asarray(sp.coo_dst)])
+    for i, j in zip(src[src < m], dst[src < m]):
+        rules = np.arange(ss[i], ss[i] + sc[i])
+        M[rules, j] += produce[rules]
+    return M
+
+
 # _assert_same_step lives in conftest.py (shared by the equivalence
 # suites); imported above under its historical local name.
 
@@ -66,7 +87,7 @@ def test_vectorized_dense_compile_matches_brute_force(name):
     system, _ = SYSTEMS[name]
     comp = compile_system(system)
     M, order = _brute_force_M(system)
-    assert comp.rule_order == order
+    assert tuple(np.asarray(comp.rule_order)) == order
     np.testing.assert_array_equal(np.asarray(comp.M), M)
 
 
@@ -76,19 +97,13 @@ def test_sparse_encoding_round_trips(name):
     sp = compile_system_sparse(system)
     M, order = _brute_force_M(system)
     n, m = system.num_rules, system.num_neurons
-    assert sp.rule_order == order
+    assert tuple(np.asarray(sp.rule_order)) == order
 
-    # ELL rows scatter back to exactly the dense M (pad column m stays 0)
-    Mr = np.zeros((n, m + 1), np.int32)
-    ec, ev = np.asarray(sp.ell_col), np.asarray(sp.ell_val)
-    np.add.at(Mr, (np.repeat(np.arange(n), ec.shape[1]), ec.ravel()),
-              ev.ravel())
-    np.testing.assert_array_equal(Mr[:, :m], M)
-    assert not Mr[:, m].any()
-    # measured ELL width is tight and nnz counts are exact
-    np.testing.assert_array_equal(np.asarray(sp.ell_nnz),
-                                  (M != 0).sum(axis=1))
-    assert sp.max_nnz_per_rule == max(1, int((M != 0).sum(axis=1).max()))
+    # the rule vectors, segments and in-adjacency rebuild exactly the
+    # dense M, in both in-adjacency layouts
+    np.testing.assert_array_equal(_rebuild_M(sp), M)
+    np.testing.assert_array_equal(
+        _rebuild_M(compile_system_sparse(system, hub_threshold=1)), M)
 
     # per-neuron segments partition the neuron-sorted rule axis
     ss, sc = np.asarray(sp.seg_start), np.asarray(sp.seg_count)
@@ -104,12 +119,18 @@ def test_sparse_encoding_round_trips(name):
         assert got == sorted(i for (i, jj) in system.synapses if jj == j)
 
 
-def test_sparse_compile_never_builds_dense_arrays():
-    sp = compile_system_sparse(ring_lattice(512, 4, seed=0))
+@pytest.mark.parametrize("system,fan_out", [
+    (ring_lattice(512, 4, seed=0), 4), (sorting(64), 64)],
+    ids=["ring-lattice-512", "sort-64"])
+def test_sparse_compile_never_builds_dense_arrays(system, fan_out):
+    """No field is O(n·m), nor n × the largest fan-out of a rule (the
+    sorting system's input rules feed all 64 middle neurons)."""
+    sp = compile_system_sparse(system)
     n, m = sp.num_rules, sp.num_neurons
     for arr in sp[:-1]:
         if hasattr(arr, "size"):
             assert arr.size < n * m / 4, "O(n·m)-sized field in sparse comp"
+            assert arr.size < n * fan_out, "n × fan-out field in sparse comp"
 
 
 def test_compile_time_regression_vectorized_adjacency():
@@ -125,7 +146,10 @@ def test_compile_time_regression_vectorized_adjacency():
     sparse_t = time.perf_counter() - t0
     assert dense_t < 8.0, f"dense compile too slow: {dense_t:.1f}s"
     assert sparse_t < 8.0, f"sparse compile too slow: {sparse_t:.1f}s"
-    assert sp.max_in_degree == 8 and sp.max_nnz_per_rule == 9
+    assert sp.max_in_degree == 8
+    # every rule writes its owner and 8 out-neighbours: 9 entries of M_Π
+    ii = np.asarray(sp.in_idx)
+    assert (np.bincount(ii[ii < sp.num_neurons]) == 8).all()
 
 
 def test_sparse_compile_rejects_unpackable_rules():

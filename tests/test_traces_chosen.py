@@ -23,8 +23,10 @@ from conftest import SEMANTICS, delayed_variant, random_states
 from repro.core import run_trace, run_traces
 from repro.core.backend import SparseBackend
 from repro.core.engine import _traces_scan, successors_per_step
-from repro.core.generators import random_system
+from repro.core.generators import power_law, random_system, sorting
 from repro.core.matrix import compile_system_sparse
+from repro.core.semantics import (TABLE_MAX_RULE_SLOTS, fired_lookup,
+                                  sparse_next_configs)
 from repro.serve import SNPTraceService, TraceRequest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -116,6 +118,46 @@ def test_step_chosen_matches_expanded_row(semantics, encoding):
         and ((n > 1) & (idx > 0)).any()
 
 
+# R = 2, 12 and 16 rules on one neuron: either side of the rule-space
+# threshold, the rule-heavy ones branching past T
+RULE_SLOTS = {"power-law": power_law(40, 3, seed=2),
+              "random-r12": random_system(10, 12, 0.4, max_spikes=6, seed=4),
+              "sort-16": sorting(16)}
+
+
+@pytest.mark.parametrize("name", sorted(RULE_SLOTS))
+def test_step_chosen_matches_expanded_row_across_rule_slots(name):
+    """The chosen successor, found in rule space past
+    ``TABLE_MAX_RULE_SLOTS`` rules on a neuron and through the table below
+    it, is the row ``sparse_next_configs`` puts at the chosen index."""
+    system = RULE_SLOTS[name]
+    comp = compile_system_sparse(system)
+    R = comp.max_rules_per_neuron
+    assert fired_lookup(comp, 1) == (
+        "rules" if R > TABLE_MAX_RULE_SLOTS else "table")
+    rng = np.random.default_rng(R)
+    cfgs = jnp.asarray(rng.integers(0, min(R, 7) + 2,
+                                    (24, system.num_neurons)), jnp.int32)
+    full = sparse_next_configs(cfgs, comp, T)
+    keys = jax.random.split(jax.random.PRNGKey(R), cfgs.shape[0])
+
+    def choose(n):
+        return jax.vmap(lambda k, c: jax.random.randint(
+            k, (), 0, jnp.maximum(c, 1)))(keys, n)
+
+    got = SparseBackend().step_chosen(cfgs, comp, T, choose)
+    n_full = jnp.sum(full.valid, axis=-1, dtype=jnp.int32)
+    idx = np.asarray(choose(n_full))
+    rows = np.arange(cfgs.shape[0])
+    np.testing.assert_array_equal(np.asarray(got.n_valid), np.asarray(n_full))
+    np.testing.assert_array_equal(np.asarray(got.configs),
+                                  np.asarray(full.configs)[rows, idx])
+    np.testing.assert_array_equal(np.asarray(got.emissions),
+                                  np.asarray(full.emissions)[rows, idx])
+    if name != "sort-16":
+        assert ((np.asarray(n_full) > 1) & (idx > 0)).any()
+
+
 def _array_shapes(jaxpr):
     """Shapes of every value an equation of ``jaxpr`` (or of a jaxpr nested
     in one, such as a scan body or a ``fori_loop``) produces."""
@@ -143,7 +185,7 @@ def test_traces_scan_builds_candidate_block_only_without_step_chosen(
     keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(B, dtype=jnp.uint32))
     scan = functools.partial(_traces_scan, steps=3, max_branches=Tb,
                              policy="random", backend=backend)
-    shapes = _array_shapes(jax.make_jaxpr(scan)(comp, c0s, keys).jaxpr)
+    shapes = _array_shapes(jax.make_jaxpr(scan)(comp, keys, c0s).jaxpr)
     assert ((B, Tb, m) in shapes) == builds_block
     assert successors_per_step(backend, Tb) == (Tb if builds_block else 1)
 

@@ -15,6 +15,7 @@ import pytest
 
 from repro.core import compile_system, explore, paper_pi, run_traces
 from repro.core.distributed import explore_distributed, run_traces_distributed
+from repro.core.generators import sorting
 from repro.runtime.faults import FaultInjector, FaultPolicy
 from repro.serve import SNPTraceService, TraceRequest
 
@@ -76,22 +77,46 @@ def test_traces_call_spans_nest_in_order(entry, tmp_path):
                   max_branches=8, backend="ref")
     spans = _spans(tmp_path)
     calls = [s for s in spans if s[0] == "snp.traces"]
-    assert [c[3] for c in calls] == [{"batch": 5}, {"batch": 5}]
+    assert [c[3] for c in calls] == [{"batch": 5, "init_rows": 1}] * 2
     for call in calls:
         assert _only(_inside(spans, call), TRACES_PHASES) == TRACES_PHASES
 
 
-@pytest.mark.parametrize("backend,successors", [("sparse", 1), ("ref", 8)])
-def test_traces_wait_span_counts_successors_per_step(backend, successors,
-                                                     tmp_path):
+@pytest.mark.parametrize("entry", [run_traces, run_traces_distributed],
+                         ids=["run_traces", "run_traces_distributed"])
+def test_traces_span_counts_init_rows(entry, tmp_path):
+    """``snp.traces`` says how many initial configurations a call got:
+    one for every trace, or one per trace."""
+    rows = np.tile(np.asarray(PI.initial_spikes), (5, 1))
+    with jax.profiler.trace(str(tmp_path)):
+        for init in (rows[0], rows):
+            entry(PI, steps=4, seeds=np.arange(5), policy="random",
+                  max_branches=8, backend="ref", init=init)
+    calls = [s[3] for s in _spans(tmp_path) if s[0] == "snp.traces"]
+    assert calls == [{"batch": 5, "init_rows": 1},
+                     {"batch": 5, "init_rows": 5}]
+
+
+SORT = sorting(12)
+
+
+@pytest.mark.parametrize("system,backend,successors,fired,slots", [
+    (PI, "sparse", 1, "table", 2), (PI, "ref", 8, "rules", 2),
+    (SORT, "sparse", 1, "rules", 12)],
+    ids=["sparse", "ref", "sparse-rule-heavy"])
+def test_traces_wait_span_counts_successors_per_step(
+        system, backend, successors, fired, slots, tmp_path):
     """``snp.traces.wait`` says how many successors each trace-step built:
     one where the backend chooses first (``step_chosen``), all ``T`` where
-    the scan expands and picks."""
+    the scan expands and picks; how the step found each neuron's fired
+    rule, in rule space or through the per-neuron table; and the rule
+    slots ``R``."""
     with jax.profiler.trace(str(tmp_path)):
-        run_traces(PI, steps=4, seeds=np.arange(3), policy="random",
+        run_traces(system, steps=4, seeds=np.arange(3), policy="random",
                    max_branches=8, backend=backend)
     waits = [s[3] for s in _spans(tmp_path) if s[0] == "snp.traces.wait"]
-    assert waits == [{"successors": successors}]
+    assert waits == [{"successors": successors, "fired": fired,
+                      "rule_slots": slots}]
 
 
 def _sleeping_runner(comp, *, steps, seeds, **_):
